@@ -41,6 +41,25 @@ def _agent_main(coordinator, cfg_dict, worker_id):
     ).run_forever(poll_interval=0.02)
 
 
+def local_workers_conflict(codec: str, local_workers: int) -> str | None:
+    """Why ``--local-workers`` cannot run this config, or None. A TPU chip
+    belongs to one process: with ``codec=tpu`` on a TPU host every local
+    agent would try to open the same chip (and this process, by asking,
+    already holds it). Run one agent per chip host instead."""
+    if codec != "tpu" or local_workers <= 0:
+        return None
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return None
+    return (
+        f"--local-workers {local_workers} with codec=tpu on a TPU host: the "
+        "chip belongs to one process, so local agents cannot share it. Start "
+        "one `python -m s3shuffle_tpu.worker` per chip host and pass "
+        "--local-workers 0, or pick a host codec."
+    )
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--serve", default="127.0.0.1:0", help="coordinator bind HOST:PORT")
@@ -77,6 +96,10 @@ def main() -> int:
     host, port = args.serve.rsplit(":", 1)
     Dispatcher.reset()
     cfg = ShuffleConfig.from_env(**overrides)
+    conflict = local_workers_conflict(cfg.codec, args.local_workers)
+    if conflict:
+        print(conflict, file=sys.stderr)
+        return 2
 
     from s3shuffle_tpu.utils import parse_size
 

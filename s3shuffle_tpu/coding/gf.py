@@ -218,28 +218,34 @@ def _encode_striped(
 
 def _encode_device(chunks: np.ndarray, coefs: np.ndarray) -> Optional[np.ndarray]:
     global _device_broken
+    from s3shuffle_tpu.ops import rates
+
     if _device_broken:
         return None
     try:
         m, k = coefs.shape
         disp = _mesh_dispatcher()
-        if disp is not None:
-            return _encode_striped(chunks, coefs, disp)
         from s3shuffle_tpu.coding import gf_pallas
 
-        if gf_pallas.supported(m, k):
+        if disp is not None:
+            out = _encode_striped(chunks, coefs, disp)
+        elif gf_pallas.supported(m, k):
             # the constant-select Pallas kernel (no table gathers);
             # interpret mode keeps it byte-exact off-chip
             import jax
 
             interpret = jax.default_backend() != "tpu"
-            return gf_pallas.encode_groups_pallas(chunks, coefs, interpret)
-        out = _device_kernel(m, k)(chunks, coefs)
-        return np.asarray(out)
+            out = gf_pallas.encode_groups_pallas(chunks, coefs, interpret)
+        else:
+            out = np.asarray(_device_kernel(m, k)(chunks, coefs))
+        rates.count_device_batch("parity", True)
+        return out
     except Exception as e:  # noqa: BLE001 — any device/toolchain failure
+        rates.count_device_batch("parity", False)
         with _device_lock:
             if not _device_broken:
                 _device_broken = True
+                rates.count_host_pin("parity")
                 logger.warning(
                     "parity device kernel unavailable, pinning host encode: %s", e
                 )

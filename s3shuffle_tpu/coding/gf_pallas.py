@@ -97,16 +97,15 @@ def _make_kernel(consts):
     return kernel
 
 
-@functools.lru_cache(maxsize=8)
-def _encode_call(gp: int, lp: int, consts, interpret: bool):
+def _pallas_call(gp: int, lp: int, consts, interpret: bool):
+    """The bare pallas_call for ``[gp, k, lp]`` groups (tests compile it for
+    a described chip; :func:`_encode_call` jits and times it)."""
     jax, jnp, pl = _jax()
     from jax.experimental.pallas import tpu as pltpu
 
-    from s3shuffle_tpu.ops import rates
-
     m = len(consts)
     k = len(consts[0])
-    call = pl.pallas_call(
+    return pl.pallas_call(
         _make_kernel(consts),
         out_shape=jax.ShapeDtypeStruct((gp, m, lp), jnp.uint8),
         grid=(gp // _TG, lp // _TL),
@@ -120,7 +119,16 @@ def _encode_call(gp: int, lp: int, consts, interpret: bool):
         ),
         interpret=interpret,
     )
-    return rates.timed_first_call("gf_encode_pallas", jax.jit(call))
+
+
+@functools.lru_cache(maxsize=8)
+def _encode_call(gp: int, lp: int, consts, interpret: bool):
+    jax, _jnp, _pl = _jax()
+    from s3shuffle_tpu.ops import rates
+
+    return rates.timed_first_call(
+        "gf_encode_pallas", jax.jit(_pallas_call(gp, lp, consts, interpret))
+    )
 
 
 def encode_groups_pallas(

@@ -299,9 +299,9 @@ class ShuffleConfig:
     # TLZ decode stays active for existing data. false = always encode TLZ.
     tpu_host_fallback: bool = True
     # seconds a device-failure host pin lasts before the codec re-probes the
-    # device with ONE trial batch (a tunnel that collapsed mid-shuffle
-    # usually comes back; the old permanent pin parked long-running workers
-    # on the host forever). 0 = the legacy permanent pin.
+    # device with ONE trial batch (a transient device fault usually clears;
+    # the old permanent pin parked long-running workers on the host
+    # forever). 0 = the legacy permanent pin.
     codec_repin_probe_s: float = 300.0
     # --- mesh plane (TPU-first addition; the reference's only data plane is
     # the object store) --- local devices the multi-chip execution plane may

@@ -133,6 +133,8 @@ KNOWN_METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # --- codec plane: measured-rate gate + Pallas kernels (ops/rates.py) ---
     "codec_path_selected_total": ("counter", ("path", "reason")),
     "codec_kernel_compile_seconds": ("histogram", ("kernel",)),
+    "codec_device_batches_total": ("counter", ("op", "outcome")),
+    "codec_host_pins_total": ("counter", ("op",)),
     # --- trace plane: span shards, flight recorder, fleet telemetry, cost
     # (utils/trace.py, metadata/service.py, s3shuffle_tpu/costs.py) ---
     "trace_shard_bytes_total": ("counter", ()),

@@ -34,13 +34,11 @@ magnitude above the JVM's table-walk.
 from __future__ import annotations
 
 import functools
-import logging
 import threading
 from typing import Dict, Tuple
 
 import numpy as np
 
-logger = logging.getLogger("s3shuffle_tpu.ops.checksum")
 
 POLY_CRC32 = 0xEDB88320  # java.util.zip.CRC32 (the reference's CRC32)
 POLY_CRC32C = 0x82F63B78  # Castagnoli (our extension / native+TPU codec)
@@ -269,23 +267,29 @@ def zero_run_crcs(poly: int, length: int) -> np.ndarray:
 
 
 def raw_crc_graph_fn(poly: int, length: int, batch: int):
-    """A traceable ``fn(data_u8) -> (B,) uint32`` raw zero-init remainder op
-    for right-aligned ``(batch, length)`` rows, safe to call INSIDE a larger
-    jit trace — the hook the fused TLZ encode kernel uses to fold the CRC
-    pass into its own launch. Picks the fused Pallas kernel when enabled and
-    the shape tiles (:func:`_use_pallas`), else the MXU bit-matmul; either
-    way the constant tables are device-resident, shipped once per poly."""
+    """``(fn, tables)``: a traceable ``fn(data_u8, *tables(device)) -> (B,)
+    uint32`` raw zero-init remainder op for right-aligned ``(batch, length)``
+    rows, safe to call INSIDE a larger jit trace — the hook the fused TLZ
+    kernels use to fold the CRC pass into their own launch — and
+    ``tables(device)``, its constant tables resident on ``device``, shipped
+    once per (poly, device). Callers pass the tables as jit ARGUMENTS placed
+    on the launch's own device: closed over, the bit-matmul's (8L, 32)
+    weight table (64 MiB at 256 KiB blocks) is constant-folded into every
+    program, ~45 s of TPU compile per launch shape; placed on another chip,
+    it is copied over on every launch. Picks the Pallas fold when enabled
+    and the shape tiles (:func:`_use_pallas`), else the MXU bit-matmul."""
     if _use_pallas(batch, length):
         from s3shuffle_tpu.ops import crc_pallas
 
-        tables = crc_pallas._device_tables(poly)
+        def fn(data_u8, w_planes, fold_m):
+            return crc_pallas.crc_raw_in_graph(data_u8, (w_planes, fold_m))
 
-        def fn(data_u8):
-            return crc_pallas.crc_raw_in_graph(data_u8, tables)
+        return fn, lambda device: crc_pallas._device_tables(poly, device)
 
-        return fn
-    w_bits = _device_weights(poly, length)
-    return lambda data_u8: _crc_math(data_u8, w_bits, length)
+    def fn(data_u8, w_bits):
+        return _crc_math(data_u8, w_bits, length)
+
+    return fn, lambda device: (_device_weights(poly, length, device),)
 
 
 def _use_pallas(b: int, length: int) -> bool:
@@ -318,15 +322,11 @@ def _use_pallas(b: int, length: int) -> bool:
             rates.record_selection("xla", "measured-host")
             return False
         reason = "measured-device"
+    import jax
+
     from s3shuffle_tpu.ops import crc_pallas
 
-    try:
-        import jax
-
-        if jax.default_backend() not in ("tpu",):
-            return False
-    except Exception:
-        logger.debug("jax backend probe failed; pallas CRC off", exc_info=True)
+    if jax.default_backend() != "tpu":
         return False
     if not crc_pallas.supported(b, length):
         return False
@@ -334,13 +334,14 @@ def _use_pallas(b: int, length: int) -> bool:
     return True
 
 
-@functools.lru_cache(maxsize=8)
-def _device_weights(poly: int, length: int):
-    """Weight table as a device-resident jax array — avoids re-shipping
-    L*8*32 bytes over the host link on every batch."""
+@functools.lru_cache(maxsize=32)
+def _device_weights(poly: int, length: int, device=None):
+    """Weight table as a jax array resident on ``device`` (None: the default
+    device) — avoids re-shipping L*8*32 bytes over the host link on every
+    batch; one copy per chip under the mesh dispatcher."""
     jax, _jnp = _jax()
     w_bits, _zero = _weights.get(poly, length)
-    return jax.device_put(w_bits)
+    return jax.device_put(w_bits, device)
 
 
 @functools.lru_cache(maxsize=8)

@@ -192,12 +192,14 @@ def fold_matrix(poly: int) -> np.ndarray:
     return m
 
 
-@functools.lru_cache(maxsize=8)
-def _device_tables(poly: int):
+@functools.lru_cache(maxsize=32)
+def _device_tables(poly: int, device=None):
+    """The fold's constant tables resident on ``device`` (None: the default
+    device), one copy per (poly, chip)."""
     jax, _jnp, _pl = _jax()
     return (
-        jax.device_put(plane_weights(poly)),
-        jax.device_put(fold_matrix(poly)),
+        jax.device_put(plane_weights(poly), device),
+        jax.device_put(fold_matrix(poly), device),
     )
 
 

@@ -58,6 +58,30 @@ _H_COMPILE = _metrics.REGISTRY.histogram(
     labelnames=("kernel",),
 )
 
+_C_DEVICE_BATCHES = _metrics.REGISTRY.counter(
+    "codec_device_batches_total",
+    "Device codec/parity batches by op (encode, decode, parity) and outcome "
+    "(ok, or failed: the batch was redone on the host)",
+    labelnames=("op", "outcome"),
+)
+_C_HOST_PINS = _metrics.REGISTRY.counter(
+    "codec_host_pins_total",
+    "Device-failure pins of a codec or of the parity encoder to the host "
+    "path, by op (encode, decode, parity)",
+    labelnames=("op",),
+)
+
+
+def count_device_batch(op: str, ok: bool) -> None:
+    if _metrics.enabled():
+        _C_DEVICE_BATCHES.labels(op=op, outcome="ok" if ok else "failed").inc()
+
+
+def count_host_pin(op: str) -> None:
+    if _metrics.enabled():
+        _C_HOST_PINS.labels(op=op).inc()
+
+
 #: cache filename shared with bench.py (kept in sync by convention; bench
 #: cannot be imported from package code — it pulls the whole harness in)
 _CACHE_BASENAME = "bench_tpu_last_good.json"
@@ -81,7 +105,7 @@ DEFAULT_HOST_RATES: Dict[str, float] = {
 #: whichever measured best on THIS rig's last probe represents the device.
 OP_METRICS: Dict[str, Tuple[Tuple[str, ...], str]] = {
     "encode": (
-        ("tpu_tlz_encode_pallas_mb_s", "tpu_tlz_encode_mb_s"),
+        ("tpu_tlz_encode_mb_s",),
         "host_tlz_encode_mb_s",
     ),
     "decode": (
@@ -351,9 +375,7 @@ def fused_decode_decision(*, forced: bool = False) -> Tuple[bool, str]:
         return False, "env-host"
     if forced:
         return True, "forced"
-    fused = best_rate(
-        "tpu_tlz_decode_fused_pallas_mb_s", "tpu_tlz_decode_fused_mb_s"
-    )
+    fused = rate("tpu_tlz_decode_fused_mb_s")
     unfused = rate("tpu_tlz_decode_mb_s")
     if fused is None or unfused is None:
         return False, "no-data"

@@ -181,10 +181,8 @@ _POP = np.array([bin(i).count("1") for i in range(256)], dtype=np.int64)
 
 def _candidate_math(blocks_u8, n_groups: int):
     """Hash + nearest-previous-identical-window candidate search — the
-    front half of the encoder, always XLA: the stable argsort at its core
-    has no Mosaic lowering, so even when the plane-decision stage runs as a
-    Pallas kernel (ops/tlz_pallas.py) this stage stays in the enclosing
-    trace. Returns (B, G) int32 candidate source POSITIONS (-1 = none)."""
+    front half of the encoder. Returns (B, G) int32 candidate source
+    POSITIONS (-1 = none)."""
     jax, jnp = _jax()
 
     mults = jnp.asarray(_MULTS_I32)
@@ -217,9 +215,8 @@ def _candidate_math(blocks_u8, n_groups: int):
 
 def _plane_decisions_math(blocks_u8, cand_d, n_groups: int):
     """Match/continuation/split decisions from the candidate positions — the
-    gather-heavy middle of the encoder, mirrored byte-for-byte by the Pallas
-    plane kernel (ops/tlz_pallas.py, regression-tested identical). Returns
-    FULL (uncompacted) planes: (is_match, is_cont, is_split, dists, ks)."""
+    gather-heavy middle of the encoder. Returns FULL (uncompacted) planes:
+    (is_match, is_cont, is_split, dists, ks)."""
     jax, jnp = _jax()
     b = blocks_u8.shape[0]
     n_bytes = n_groups * GROUP
@@ -379,8 +376,7 @@ def _encode_math(blocks_u8, n_groups: int):
     """The raw (unjitted) encode computation — shared by the standalone
     jitted kernel and larger fused traces (see __graft_entry__). Composition
     of the three encoder stages (candidate search → plane decisions →
-    compaction); the Pallas encode path swaps ONLY the middle stage
-    (ops/tlz_pallas.py _encode_math_pallas). Returns
+    compaction). Returns
     (match_bitmap, cont_bitmap, split_bitmap, dists_compact, ks_compact,
     lits_compact, n_new, n_split, n_match) where ``dists_compact[:, :n_new]``
     are the stored (non-continuation) match distances,
@@ -397,7 +393,7 @@ def _encode_kernel(n_groups: int):
     return jax.jit(functools.partial(_encode_math, n_groups=n_groups))
 
 
-def _encode_fused_math(blocks_u8, n_groups: int, crc_fn, encode_fn=None):
+def _encode_fused_math(blocks_u8, n_groups: int, crc_fn):
     """Encode + fused CRC in ONE trace: the planes of :func:`_encode_math`
     plus, from the same launch, raw zero-init CRC remainders of (a) each raw
     input block (the framing raw-escape branch checksums stored RAW bytes)
@@ -405,11 +401,9 @@ def _encode_fused_math(blocks_u8, n_groups: int, crc_fn, encode_fn=None):
     a TLZ payload — the host stitches the small header/metadata CRCs around
     it with :func:`ops.checksum.crc_combine`). Both remainder batches ride
     one (2B, L) CRC pass, so the separate checksum launch — and its second
-    H2D staging of every compressed byte — disappears. ``encode_fn`` swaps
-    the plane computation (default :func:`_encode_math`; the Pallas path
-    passes its own — same 9-tuple contract)."""
+    H2D staging of every compressed byte — disappears."""
     _jax_mod, jnp = _jax()
-    outs = (encode_fn or _encode_math)(blocks_u8, n_groups)
+    outs = _encode_math(blocks_u8, n_groups)
     lits, n_split, n_match = outs[5], outs[7], outs[8]
     b = blocks_u8.shape[0]
     n_bytes = n_groups * GROUP
@@ -426,72 +420,42 @@ def _encode_fused_math(blocks_u8, n_groups: int, crc_fn, encode_fn=None):
     return outs + (raw[:b], raw[b:])
 
 
-def _encode_impl() -> str:
-    """Which device encode formulation represents the chip: ``pallas`` (the
-    VMEM plane kernel, ops/tlz_pallas.py) when ``S3SHUFFLE_TLZ_PALLAS=1`` or
-    the measured-rate table clocks it above the XLA graph, else ``xla``.
-    This is a WITHIN-device choice — whether the device runs at all is the
-    codec's rate gate (codec/tpu.py + ops/rates.py)."""
-    import os
-
-    env = os.environ.get("S3SHUFFLE_TLZ_PALLAS")
-    if env is not None:
-        return "pallas" if env.strip() == "1" else "xla"
-    from s3shuffle_tpu.ops import rates
-
-    p = rates.rate("tpu_tlz_encode_pallas_mb_s")
-    x = rates.rate("tpu_tlz_encode_mb_s")
-    if p is not None and (x is None or p > x):
-        return "pallas"
-    return "xla"
-
-
-def _decode_fused_impl() -> str:
-    """Pallas vs XLA formulation of the FUSED decode launch (same contract
-    as :func:`_encode_impl`; the unfused decode has no Pallas variant)."""
-    import os
-
-    env = os.environ.get("S3SHUFFLE_TLZ_PALLAS")
-    if env is not None:
-        return "pallas" if env.strip() == "1" else "xla"
-    from s3shuffle_tpu.ops import rates
-
-    p = rates.rate("tpu_tlz_decode_fused_pallas_mb_s")
-    x = rates.rate("tpu_tlz_decode_fused_mb_s")
-    if p is not None and (x is None or p > x):
-        return "pallas"
-    return "xla"
-
-
 @functools.lru_cache(maxsize=16)
-def _batch_kernel(batch_rows: int, n_groups: int, poly: Optional[int],
-                  impl: str = "xla"):
+def _batch_kernel(batch_rows: int, n_groups: int, poly: Optional[int]):
     """Precompiled fixed-shape batched encode kernel — one trace per
-    (batch rows, block shape, fused poly, impl), never per call: a varying
+    (batch rows, block shape, fused poly), never per call: a varying
     batch dim retraces per distinct size under jit (XLA compiles per shape),
     which taxed every tail batch on the old path. The staged batch is
     DONATED so XLA may reuse its device buffer for outputs. ``poly`` selects
-    the fused CRC variant (None = encode planes only); ``impl`` selects the
-    plane-decision stage (``xla`` graph or the ``pallas`` VMEM kernel —
-    byte-identical outputs, regression-tested)."""
+    the fused CRC variant (None = encode planes only)."""
     jax, _jnp = _jax()
-    if impl == "pallas":
-        from s3shuffle_tpu.ops import tlz_pallas
+    from s3shuffle_tpu.ops import rates
 
-        stage_fn = tlz_pallas.encode_math_fn(n_groups)
-    else:
-        stage_fn = functools.partial(_encode_math, n_groups=n_groups)
     if poly is None:
-        fn = stage_fn
-    else:
-        from s3shuffle_tpu.ops.checksum import raw_crc_graph_fn
+        return rates.timed_first_call("tlz_encode", jax.jit(
+            functools.partial(_encode_math, n_groups=n_groups),
+            donate_argnums=(0,),
+        ))
+    from s3shuffle_tpu.ops.checksum import raw_crc_graph_fn
 
-        crc_fn = raw_crc_graph_fn(poly, n_groups * GROUP, 2 * batch_rows)
-        fn = functools.partial(
-            _encode_fused_math, n_groups=n_groups, crc_fn=crc_fn,
-            encode_fn=lambda blocks, _n: stage_fn(blocks),
-        )
-    return jax.jit(fn, donate_argnums=(0,))
+    crc_fn, tables = raw_crc_graph_fn(poly, n_groups * GROUP, 2 * batch_rows)
+    kernel = jax.jit(
+        lambda blocks, *t: _encode_fused_math(
+            blocks, n_groups, lambda d: crc_fn(d, *t)
+        ),
+        donate_argnums=(0,),
+    )
+    return rates.timed_first_call(
+        "tlz_encode_fused",
+        lambda blocks: kernel(blocks, *tables(_device_of(blocks))),
+    )
+
+
+def _device_of(arr):
+    """The one device a staged launch input lives on: the fused kernels'
+    CRC tables must sit on the same chip, or every launch copies them."""
+    (device,) = arr.devices()
+    return device
 
 
 def _bucket_rows(n: int, cap: int) -> int:
@@ -704,7 +668,7 @@ def encode_batch_device(
                 warnings.filterwarnings(
                     "ignore", message="Some donated buffers were not usable"
                 )
-                outs = _batch_kernel(rows, n_groups, poly, _encode_impl())(
+                outs = _batch_kernel(rows, n_groups, poly)(
                     jax.device_put(staged, disp.device(slot))
                     if disp is not None
                     else jax.device_put(staged)
@@ -1425,32 +1389,34 @@ def _decode_kernel(n_groups: int):
 
 
 @functools.lru_cache(maxsize=16)
-def _decode_batch_kernel(batch_rows: int, n_groups: int, poly: Optional[int],
-                         impl: str = "xla"):
+def _decode_batch_kernel(batch_rows: int, n_groups: int, poly: Optional[int]):
     """Precompiled fixed-shape batched decode kernel — one trace per
-    (batch rows, block shape, fused poly, impl), never per call: the old
+    (batch rows, block shape, fused poly), never per call: the old
     path jitted over whatever batch size arrived, so XLA recompiled per
     distinct frame-run length (every tail run of every partition). Staged
     plane arrays are DONATED so XLA may reuse their device buffers. ``poly``
-    selects the fused CRC variant (None = decode only); ``impl="pallas"``
-    (fused only) runs plane reconstruction AND the CRC fold in ONE Pallas
-    grid (ops/tlz_pallas.py) instead of serializing a second launch."""
+    selects the fused CRC variant (None = decode only)."""
     jax, _jnp = _jax()
-    if poly is None:
-        fn = functools.partial(_decode_math, n_groups=n_groups)
-        return jax.jit(fn, donate_argnums=(0, 1, 2, 3, 4, 5))
-    if impl == "pallas":
-        from s3shuffle_tpu.ops import tlz_pallas
+    from s3shuffle_tpu.ops import rates
 
-        fn = tlz_pallas.decode_fused_math_fn(n_groups, poly)
-        return jax.jit(fn, donate_argnums=(0, 1, 2, 3, 4, 5))
+    if poly is None:
+        return rates.timed_first_call("tlz_decode", jax.jit(
+            functools.partial(_decode_math, n_groups=n_groups),
+            donate_argnums=(0, 1, 2, 3, 4, 5),
+        ))
     from s3shuffle_tpu.ops.checksum import raw_crc_graph_fn
 
-    crc_fn = raw_crc_graph_fn(poly, n_groups * GROUP, batch_rows)
-    fn = functools.partial(
-        _decode_fused_math, n_groups=n_groups, crc_fn=crc_fn
+    crc_fn, tables = raw_crc_graph_fn(poly, n_groups * GROUP, batch_rows)
+    kernel = jax.jit(
+        lambda m, c, s, o, k, lits, n_lits, *t: _decode_fused_math(
+            m, c, s, o, k, lits, n_lits, n_groups, lambda d: crc_fn(d, *t)
+        ),
+        donate_argnums=(0, 1, 2, 3, 4, 5),
     )
-    return jax.jit(fn, donate_argnums=(0, 1, 2, 3, 4, 5))
+    return rates.timed_first_call(
+        "tlz_decode_fused",
+        lambda *planes: kernel(*planes, *tables(_device_of(planes[0]))),
+    )
 
 
 class _DecodeStaging(threading.local):
@@ -1756,9 +1722,7 @@ def decode_batch_device(
                         _put(offs), _put(ks), _put(lits),
                     )
                 else:
-                    outs = _decode_batch_kernel(
-                        launch_rows, n_groups, poly, _decode_fused_impl()
-                    )(
+                    outs = _decode_batch_kernel(launch_rows, n_groups, poly)(
                         _put(is_match), _put(is_cont), _put(is_split),
                         _put(offs), _put(ks), _put(lits), _put(nlits),
                     )

@@ -5,16 +5,6 @@ from __future__ import annotations
 from typing import Mapping, Optional, Sequence
 
 
-def get_shard_map():
-    """Version-tolerant ``shard_map``: jax >= 0.4.35 exports it at top
-    level, older releases only under ``jax.experimental``."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def make_mesh(axes: Optional[Mapping[str, int]] = None, devices=None):
     """Build a ``jax.sharding.Mesh``.
 

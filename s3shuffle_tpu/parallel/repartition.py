@@ -92,13 +92,7 @@ def device_repartition(mesh, rows, part_ids, axis: str = "data", capacity: int |
     small and rows were dropped; callers must treat that as an error and
     retry via the store path or a larger capacity).
     """
-    import jax
     import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from s3shuffle_tpu.parallel.mesh import get_shard_map
-
-    shard_map = get_shard_map()
 
     n_dev = mesh.shape[axis]
     n, row_bytes = rows.shape
@@ -108,16 +102,8 @@ def device_repartition(mesh, rows, part_ids, axis: str = "data", capacity: int |
     if capacity is None:
         capacity = plan_capacity(local_n, n_dev)
 
-    step = _repartition_fn(axis, n_dev, capacity, row_bytes)
-    spec_rows = P(axis, None)
-    spec_ids = P(axis)
-    sharded = shard_map(
-        step,
-        mesh=mesh,
-        in_specs=(spec_rows, spec_ids),
-        out_specs=(P(axis, None), P(axis), P(axis), P(axis)),
-    )
-    recv, recv_ids, valid, overflow = jax.jit(sharded)(rows, part_ids)
+    step = sharded_step(mesh, axis, capacity, row_bytes)
+    recv, recv_ids, valid, overflow = step(rows, part_ids)
     total_overflow = int(jnp.sum(overflow))
     if total_overflow:
         raise ValueError(
@@ -125,3 +111,22 @@ def device_repartition(mesh, rows, part_ids, axis: str = "data", capacity: int |
             f"{capacity}; increase capacity/slack or use the store path"
         )
     return recv, recv_ids, valid
+
+
+def sharded_step(mesh, axis: str, capacity: int, row_bytes: int):
+    """The jitted ``shard_map`` repartition step over ``mesh``: takes
+    (rows [N, row_bytes] u8, part_ids [N] i32) sharded on ``axis`` and
+    returns per-device (received_rows, received_ids, valid_mask, overflow).
+    One ``all_to_all`` per payload; callers lower it against a described
+    topology too (tests/test_chip_compile.py)."""
+    import jax
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    step = _repartition_fn(axis, mesh.shape[axis], capacity, row_bytes)
+    return jax.jit(shard_map(
+        step,
+        mesh=mesh,
+        in_specs=(P(axis, None), P(axis)),
+        out_specs=(P(axis, None), P(axis), P(axis), P(axis)),
+    ))

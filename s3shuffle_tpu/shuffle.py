@@ -236,16 +236,9 @@ class ShuffleContext:
         width = 0
         requested = _mesh_dispatch.requested_devices()
         if requested >= 2:
-            try:
-                import jax
+            import jax
 
-                width = min(requested, len(jax.local_devices()))
-            except Exception:  # noqa: BLE001 — backend init failure = host path
-                logger.warning(
-                    "mesh plane requested but device enumeration failed; "
-                    "using the host path", exc_info=True,
-                )
-                width = 0
+            width = min(requested, len(jax.local_devices()))
 
         handle = None
         used_mesh = False

@@ -22,26 +22,24 @@ def test_gate_fails_nonzero_on_regression(tmp_path, capsys):
     cache = tmp_path / "rates.json"
     cache.write_text(json.dumps({
         "measured_at_utc": "2026-08-04T01:44:37Z",
-        "tpu_tlz_encode_pallas_mb_s": 3.6,
+        "tpu_tlz_encode_mb_s": 3.6,
         "tpu_tlz_decode_mb_s": 1004.2,
         "tpu_tlz_decode_fused_mb_s": 51.2,
     }))
     rc = chip_gate.main(["--cache", str(cache)])
     out = capsys.readouterr().out
     assert rc == 1
-    assert "MISS" in out and "tpu_tlz_encode_pallas_mb_s" in out
+    assert "MISS" in out and "tpu_tlz_encode_mb_s" in out
     assert "below floor/target" in out
 
 
 def test_gate_passes_when_kernels_beat_floors(tmp_path, capsys):
     cache = tmp_path / "rates.json"
     cache.write_text(json.dumps({
-        "tpu_tlz_encode_pallas_mb_s": 600.0,
         "tpu_crc32c_pallas_mb_s": 2000.0,
         "tpu_gf_encode_mb_s": 950.0,
         "tpu_tlz_decode_mb_s": 1004.2,
         "tpu_tlz_decode_fused_mb_s": 950.0,
-        "tpu_tlz_decode_fused_pallas_mb_s": 1100.0,
         "tpu_tlz_encode_mb_s": 590.0,
         "tpu_tlz_encode_fused_mb_s": 560.0,
     }))
@@ -72,14 +70,13 @@ def test_new_probe_fields_survive_merge():
         "tpu_tlz_decode_mb_s": 1004.2,
     }
     fresh = {
-        "tpu_tlz_encode_pallas_mb_s": 620.0,
-        "tpu_tlz_encode_pallas_cold_s": 4.1,
+        "tpu_tlz_encode_fused_mb_s": 620.0,
+        "tpu_tlz_encode_fused_cold_s": 4.1,
         "tpu_crc32c_pallas_mb_s": 1900.0,
-        "tpu_tlz_decode_fused_pallas_mb_s": 880.0,
         "tpu_gf_encode_mb_s": 910.0,
     }
     merged = chip_gate.merge_probe_metrics(cached, fresh)
-    # every new pallas field landed, cold-compile fields included
+    # every new field landed, cold-compile fields included
     for k, v in fresh.items():
         assert merged[k] == v
     # prior metrics the fresh probe did not re-measure are kept

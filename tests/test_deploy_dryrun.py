@@ -191,3 +191,21 @@ def test_deploy_wiring_executes_end_to_end(tmp_path):
             coord.wait()
         if coord.stdout:
             coord.stdout.close()
+
+
+def test_local_workers_refuse_codec_tpu_on_a_tpu_host(monkeypatch):
+    """A chip belongs to one process: --local-workers cannot share it."""
+    import importlib.util
+
+    import jax
+
+    spec = importlib.util.spec_from_file_location(
+        "multihost_terasort", REPO / "examples" / "multihost_terasort.py"
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    assert mod.local_workers_conflict("tpu", 2) is None  # CPU host here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert "one process" in mod.local_workers_conflict("tpu", 2)
+    assert mod.local_workers_conflict("tpu", 0) is None
+    assert mod.local_workers_conflict("native", 2) is None

@@ -1,5 +1,5 @@
 """Device-resident codec pipeline: batched fixed-shape launches, fused
-CRC32C, async encode overlap, probe-race hardening, and the single-block
+CRC32C, async encode overlap, and the single-block
 tail fix (PR 8)."""
 
 import io
@@ -123,84 +123,56 @@ def test_tpu_frame_blocks_single_full_block_uses_device_batch(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# Satellite: probe-race hardening — one routing snapshot per batch
+# Backend discovery: one synchronous decision per process, errors raise
 # ---------------------------------------------------------------------------
 
 
-def test_probe_flip_between_batches_keeps_each_batch_consistent(monkeypatch):
-    """The delegate decision is snapshotted ONCE per frame_blocks call: with
-    a probe whose verdict flips on every consultation, every emitted batch
-    must still decode and carry internally consistent codec ids."""
+def test_backend_decided_synchronously_before_the_first_batch(monkeypatch):
+    """No discovery thread and no pending window: the first batch already
+    sees the process's one backend verdict (a CPU here, so the documented
+    host fallback reroutes every frame of it to SLZ)."""
+    import jax
+
     from s3shuffle_tpu.codec import tpu as tpu_mod
     from s3shuffle_tpu.codec.native import native_available
 
     if not native_available():
         pytest.skip("native SLZ library not built")
-    flips = {"n": 0}
-
-    def flapping_probe():
-        flips["n"] += 1
-        # pending → resolved-host → pending → ... : the worst-case flapping
-        # tunnel; a per-frame re-read would split one batch across codecs
-        return (False, False) if flips["n"] % 2 else (False, True)
-
-    monkeypatch.setattr(tpu_mod, "_probe_state", flapping_probe)
+    calls = []
+    real = jax.default_backend
+    monkeypatch.setattr(jax, "default_backend",
+                        lambda: calls.append(1) or real())
+    monkeypatch.setattr(tpu_mod, "_BACKEND_VERDICT", None)
+    monkeypatch.delenv("S3SHUFFLE_TPU_CODEC_DEVICE", raising=False)
+    threads_before = threading.active_count()
     codec = TpuCodec(block_size=BS, batch_blocks=4, host_encode_fallback=True)
     data = _mixed_payload(random.Random(2), BS * 4)
     blocks = [data[i * BS : (i + 1) * BS] for i in range(4)]
-    for _ in range(6):
+    for _ in range(3):
         framed = codec.frame_blocks(blocks)
-        # each batch decodes as one stream regardless of which codec took it
-        got = CodecInputStream(codec, io.BytesIO(framed)).read()
-        assert got == data
-        # and every frame in ONE batch carries the same routing family
-        ids = set()
+        assert CodecInputStream(codec, io.BytesIO(framed)).read() == data
         ofs = 0
-        while ofs < len(framed):
-            cid = framed[ofs]
-            clen = int(np.frombuffer(framed[ofs + 5 : ofs + 9], "<u4")[0])
-            if cid != 0:  # raw escape is legal under either routing
-                ids.add(cid)
-            ofs += 9 + clen
-        assert len(ids) <= 1, f"one batch split across codecs: {ids}"
+        while ofs < len(framed):  # every frame SLZ (or a raw escape)
+            assert framed[ofs] in (CODEC_IDS["raw"], CODEC_IDS["native-lz"])
+            ofs += 9 + int(np.frombuffer(framed[ofs + 5 : ofs + 9], "<u4")[0])
+    assert len(calls) == 1
+    assert threading.active_count() == threads_before
 
 
-def test_probe_resolution_race_two_threads(monkeypatch):
-    """A worker thread encodes streams while another thread resolves the
-    probe mid-run (the codec/framing race note): every stream must decode,
-    under both the delegate and the TLZ routing."""
+def test_failed_backend_query_raises(monkeypatch):
+    import jax
+
     from s3shuffle_tpu.codec import tpu as tpu_mod
-    from s3shuffle_tpu.codec.native import native_available
 
-    if not native_available():
-        pytest.skip("native SLZ library not built")
-    state = {"resolved": False}
-    monkeypatch.setattr(
-        tpu_mod, "_probe_state",
-        lambda: (False, True) if state["resolved"] else (False, False),
-    )
-    codec = TpuCodec(block_size=BS, batch_blocks=2, host_encode_fallback=True)
-    data = _mixed_payload(random.Random(3), BS * 6 + 123)
-    errors = []
-    done = threading.Event()
+    def broken():
+        raise RuntimeError("backend init failed")
 
-    def writer():
-        try:
-            for _ in range(40):
-                framed = _stream_compress(codec, data, chunk=BS - 7)
-                assert codec.decompress_bytes(framed) == data
-        except Exception as e:  # surfaced via the errors list below
-            errors.append(e)
-        finally:
-            done.set()
-
-    t = threading.Thread(target=writer, daemon=True)
-    t.start()
-    # resolve the probe mid-run — the race under test
-    while not done.is_set() and not state["resolved"]:
-        state["resolved"] = True
-    t.join(timeout=60)
-    assert not t.is_alive() and not errors, errors
+    monkeypatch.setattr(jax, "default_backend", broken)
+    monkeypatch.setattr(tpu_mod, "_BACKEND_VERDICT", None)
+    monkeypatch.delenv("S3SHUFFLE_TPU_CODEC_DEVICE", raising=False)
+    codec = TpuCodec(block_size=BS, batch_blocks=4)
+    with pytest.raises(RuntimeError, match="backend init failed"):
+        codec.compress_blocks([bytes(BS)])
 
 
 # ---------------------------------------------------------------------------
@@ -364,7 +336,7 @@ def test_repeated_device_failures_pin_codec_to_host(monkeypatch, caplog):
     import logging
 
     def always_fails(*a, **kw):
-        raise RuntimeError("tunnel is gone")
+        raise RuntimeError("device lost")
 
     monkeypatch.setattr(tlz, "encode_batch_device", always_fails)
     codec = TpuCodec(block_size=BS, batch_blocks=2, use_device=True)

@@ -182,7 +182,7 @@ def test_repeated_decode_failures_pin_codec_to_host(monkeypatch, caplog):
     import logging
 
     def always_fails(*a, **kw):
-        raise RuntimeError("tunnel is gone")
+        raise RuntimeError("device lost")
 
     monkeypatch.setattr(tlz, "decode_batch_device", always_fails)
     data = _mixed_payload(random.Random(6), BS * 2)
@@ -646,7 +646,12 @@ def _run_shuffle_read(tmp_path, tag, retries, fault=False, **cfg_extra):
                 exc=transient_connection_reset,
             ))
             disp.backend = flaky
-        return sorted(ctx.group_by_key(parts, num_partitions=3))
+        # equal-key value order depends on block arrival order, which the
+        # async read window does not fix: compare each key's value multiset
+        return sorted(
+            (k, sorted(vs))
+            for k, vs in ctx.group_by_key(parts, num_partitions=3)
+        )
 
 
 def test_async_window_transient_fault_heals_with_retries(tmp_path):

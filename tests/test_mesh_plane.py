@@ -445,3 +445,56 @@ def test_encode_decode_bytes_identical_armed_vs_disarmed():
     assert mesh_blocks == ref_blocks
     assert ref_blocks == [data[i].tobytes() for i in range(blocks)]
     assert disp.outstanding_snapshot() == [0] * 8
+
+
+@pytest.mark.parametrize("op", ["encode", "decode"])
+def test_fused_crc_tables_live_on_the_launch_device(monkeypatch, op):
+    """Under the armed dispatcher each fused launch takes the CRC tables
+    resident on its own staging device — never the default device's copy,
+    which would be copied to the launch's chip on every call."""
+    from s3shuffle_tpu.ops import checksum, tlz
+    from s3shuffle_tpu.ops.checksum import POLY_CRC32C
+
+    monkeypatch.setenv("S3SHUFFLE_PALLAS_CRC", "0")  # the XLA weight table
+    block, blocks, batch = 2048, 16, 4
+    data = np.tile(np.arange(block, dtype=np.uint8) % 7, (blocks, 1))
+    payloads, _ = tlz.encode_batch_device(
+        data.tobytes(), blocks, block, batch_blocks=batch, poly=POLY_CRC32C
+    )
+    tlz._batch_kernel.cache_clear()
+    tlz._decode_batch_kernel.cache_clear()
+    dispatch.configure(4)
+    assert dispatch.get_dispatcher().n_devices == 4
+
+    launches = []
+    real_of = tlz._device_of
+
+    def spy_of(arr):
+        dev = real_of(arr)
+        launches.append(dev)
+        return dev
+
+    placed = []
+    real_weights = checksum._device_weights
+
+    def spy_weights(poly, length, device=None):
+        tables = real_weights(poly, length, device)
+        placed.append((device, tables.devices()))
+        return tables
+
+    monkeypatch.setattr(tlz, "_device_of", spy_of)
+    monkeypatch.setattr(checksum, "_device_weights", spy_weights)
+    try:
+        if op == "encode":
+            tlz.encode_batch_device(data.tobytes(), blocks, block,
+                                    batch_blocks=batch, poly=POLY_CRC32C)
+        else:
+            tlz.decode_batch_device(payloads, [block] * blocks, block,
+                                    batch_rows=batch, poly=POLY_CRC32C)
+    finally:
+        tlz._batch_kernel.cache_clear()
+        tlz._decode_batch_kernel.cache_clear()
+    assert len(launches) == blocks // batch
+    assert len(set(launches)) > 1  # the dispatcher spread the launches
+    assert [dev for dev, _ in placed] == launches
+    assert all(devs == {dev} for dev, devs in placed)

@@ -1,16 +1,16 @@
-"""Seeded interpret-mode property suites for the hand-written Pallas kernels.
+"""Seeded property suites for the device codec kernels.
 
-On the CPU test mesh every kernel runs in Pallas interpret mode
+On the CPU test mesh the Pallas kernels run in interpret mode
 (``jax.default_backend() != "tpu"``), which executes the exact same kernel
 program through the JAX interpreter — so CI proves byte-identity without a
-chip: TLZ encode against the host C encoder, the fused decode+CRC against
-the host decode and native crc32c, the tiled CRC fold against the host raw
-remainder, and the GF(2^8) parity kernel against the numpy table encoder,
-plus mid-kernel failure falling back host-side without losing a frame.
-
-``S3SHUFFLE_TLZ_PALLAS=1`` forces the within-device impl choice to the
-Pallas formulation (ops/tlz.py _encode_impl/_decode_fused_impl), so these
-suites drive the REAL production entry points, not kernel internals.
+chip: the device TLZ encode against the numpy encoder (which makes the
+device graph's match decisions at every width; the host C encoder shares
+the wire format and agrees only at small blocks, ROADMAP D4), the fused
+decode+CRC against the host decode and native crc32c, the tiled CRC fold
+against the host raw remainder, and the GF(2^8) parity kernel against the
+numpy table encoder, plus mid-kernel failure falling back host-side without
+losing a frame (and counted). tests/test_chip_compile.py compiles the same
+kernels for a described v5e.
 """
 
 import numpy as np
@@ -18,20 +18,14 @@ import pytest
 
 import s3shuffle_tpu.codec.tpu as tpu_mod
 from s3shuffle_tpu.codec.tpu import TpuCodec
-from s3shuffle_tpu.ops import crc_pallas, tlz, tlz_pallas
+from s3shuffle_tpu.metrics import registry as mreg
+from s3shuffle_tpu.ops import crc_pallas, tlz
 from s3shuffle_tpu.ops.checksum import POLY_CRC32C, _crc_raw_bytes
 from s3shuffle_tpu.utils.checksums import crc32c_py
 
 
-@pytest.fixture
-def force_pallas(monkeypatch):
-    monkeypatch.setenv("S3SHUFFLE_TLZ_PALLAS", "1")
-
-
 def _host_payload(data: bytes) -> bytes:
-    native = tlz._encode_block_native(data)
-    if native is not None:
-        return native
+    """The device encode's host oracle: the numpy encoder."""
     return tlz._assemble_payload_numpy(data)
 
 
@@ -49,15 +43,13 @@ def _make_block(kind: str, size: int, rng) -> bytes:
 
 
 # ---------------------------------------------------------------------------
-# TLZ encode: Pallas plane kernel byte-identical to the host C encoder
+# TLZ encode: device batch kernel byte-identical to the numpy encoder
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("block_size", [512, 2048])
 @pytest.mark.parametrize("n_blocks", [1, 3, 4])  # 3 = padded tail bucket
-def test_pallas_encode_byte_identical_to_host(
-    force_pallas, block_size, n_blocks
-):
+def test_device_encode_byte_identical_to_numpy_encoder(block_size, n_blocks):
     rng = np.random.default_rng(block_size * 31 + n_blocks)
     kinds = ["text", "random", "zeros", "mixed"]
     blocks = [
@@ -65,7 +57,6 @@ def test_pallas_encode_byte_identical_to_host(
         for i in range(n_blocks)
     ]
     blob = b"".join(blocks)
-    assert tlz._encode_impl() == "pallas"
     payloads, _ = tlz.encode_batch_device(
         blob, n_blocks, block_size, batch_blocks=4
     )
@@ -73,11 +64,50 @@ def test_pallas_encode_byte_identical_to_host(
         assert bytes(payload) == _host_payload(data)
         assert bytes(tlz.decode_payload_numpy(bytes(payload),
                                               block_size)) == data
+        # at these small widths the C encoder happens to choose the same
+        # matches; at the codec's 256 KiB width it does not (below)
+        native = tlz._encode_block_native(data)
+        assert native is None or native == bytes(payload)
 
 
-def test_pallas_fused_encode_crcs_match_host(force_pallas):
-    """poly= routes through _encode_fused_math with the Pallas plane stage:
-    payloads stay byte-identical AND the fused raw-block CRCs are true."""
+def test_device_encode_at_codec_width_matches_numpy_encoder():
+    """At the codec's real width (256 KiB blocks of serialized terasort
+    records) the device graph is byte-identical to the numpy encoder. The
+    host C encoder picks other matches there (50 of 64 blocks in the chip
+    smoke's Phase B data), so it is held only to decoding to the input
+    (ROADMAP D4 tracks aligning it)."""
+    import importlib.util
+    import io
+    import os
+
+    from s3shuffle_tpu.batch import write_frame
+
+    spec = importlib.util.spec_from_file_location(
+        "terasort_example",
+        os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                     "examples", "terasort.py"),
+    )
+    terasort = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(terasort)
+    bs = 256 * 1024  # TpuCodec default block_size
+    buf = io.BytesIO()
+    for part in terasort.generate(3 * bs, 1, seed=3):
+        write_frame(buf, part)
+    raw = buf.getvalue()
+    blocks = [raw[i * bs:(i + 1) * bs] for i in range(2)]
+    payloads, _ = tlz.encode_batch_device(b"".join(blocks), 2, bs,
+                                          batch_blocks=2)
+    for data, payload in zip(blocks, payloads):
+        assert bytes(payload) == tlz._assemble_payload_numpy(data)
+        assert bytes(tlz.decode_payload_numpy(bytes(payload), bs)) == data
+        native = tlz._encode_block_native(data)
+        if native is not None:  # same wire format, its own matches
+            assert bytes(tlz.decode_payload_numpy(native, bs)) == data
+
+
+def test_device_fused_encode_crcs_match_host():
+    """poly= routes through _encode_fused_math: payloads stay
+    byte-identical AND the fused raw-block CRCs are true."""
     bs = 1024
     rng = np.random.default_rng(99)
     blocks = [_make_block(k, bs, rng) for k in ("text", "mixed")]
@@ -93,18 +123,17 @@ def test_pallas_fused_encode_crcs_match_host(force_pallas):
 
 
 # ---------------------------------------------------------------------------
-# Fused decode: Pallas grid reconstruction + in-kernel CRC
+# Fused decode: plane reconstruction + CRC in one launch
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("n_blocks", [2, 4])
-def test_pallas_fused_decode_roundtrip_and_crc(force_pallas, n_blocks):
+def test_device_fused_decode_roundtrip_and_crc(n_blocks):
     bs = 1024
     rng = np.random.default_rng(n_blocks * 7)
     kinds = ["text", "mixed", "zeros", "random"]
     blocks = [_make_block(kinds[i], bs, rng) for i in range(n_blocks)]
     payloads = [_host_payload(b) for b in blocks]
-    assert tlz._decode_fused_impl() == "pallas"
     dec, crcs = tlz.decode_batch_device(
         payloads, [bs] * n_blocks, bs, batch_rows=4, poly=POLY_CRC32C
     )
@@ -112,25 +141,6 @@ def test_pallas_fused_decode_roundtrip_and_crc(force_pallas, n_blocks):
         assert bytes(dec[i]) == blocks[i]
         assert crcs[i] is not None
         assert int(crcs[i]) == crc32c_py(payloads[i])
-
-
-def test_pallas_fused_decode_matches_xla_formulation(monkeypatch):
-    """The two fused-decode formulations must agree bit-for-bit on decoded
-    bytes AND certificates — the gate may pick either per the rate table."""
-    bs = 1024
-    rng = np.random.default_rng(5)
-    blocks = [_make_block(k, bs, rng) for k in ("mixed", "text")]
-    payloads = [_host_payload(b) for b in blocks]
-    results = {}
-    for impl in ("1", "0"):
-        monkeypatch.setenv("S3SHUFFLE_TLZ_PALLAS", impl)
-        results[impl] = tlz.decode_batch_device(
-            payloads, [bs] * 2, bs, batch_rows=2, poly=POLY_CRC32C
-        )
-    dec_p, crc_p = results["1"]
-    dec_x, crc_x = results["0"]
-    assert [bytes(d) for d in dec_p] == [bytes(d) for d in dec_x]
-    assert [int(c) for c in crc_p] == [int(c) for c in crc_x]
 
 
 # ---------------------------------------------------------------------------
@@ -223,8 +233,30 @@ def test_pallas_gf_parity_recovers_erased_chunks():
 # ---------------------------------------------------------------------------
 
 
+def _device_counters():
+    snap = mreg.REGISTRY.snapshot()
+    batches = {
+        (s["labels"]["op"], s["labels"]["outcome"]): s["value"]
+        for s in snap["codec_device_batches_total"]["series"]
+    }
+    pins = {
+        s["labels"]["op"]: s["value"]
+        for s in snap["codec_host_pins_total"]["series"]
+    }
+    return batches, pins
+
+
+@pytest.fixture
+def counting():
+    mreg.REGISTRY.reset_values()
+    mreg.enable()
+    yield
+    mreg.disable()
+    mreg.REGISTRY.reset_values()
+
+
 def test_encode_kernel_failure_falls_back_without_frame_loss(
-    force_pallas, monkeypatch
+    monkeypatch, counting
 ):
     bs = 1024
     rng = np.random.default_rng(1)
@@ -238,17 +270,23 @@ def test_encode_kernel_failure_falls_back_without_frame_loss(
         return boom
 
     monkeypatch.setattr(tpu_mod.tlz, "_batch_kernel", broken_kernel)
-    payloads, crcs = codec._encode_full_blocks(
-        memoryview(b"".join(blocks)), 2, bs, None
-    )
-    assert crcs is None
-    assert [bytes(p) for p in payloads] == [_host_payload(b) for b in blocks]
+    for _ in range(3):  # the third consecutive failure pins the host
+        payloads, crcs = codec._encode_full_blocks(
+            memoryview(b"".join(blocks)), 2, bs, None
+        )
+        assert crcs is None
+        assert [bytes(p) for p in payloads] == [
+            _host_payload(b) for b in blocks
+        ]
     for data, payload in zip(blocks, payloads):
         assert bytes(tlz.decode_payload_numpy(bytes(payload), bs)) == data
+    batches, pins = _device_counters()
+    assert batches == {("encode", "failed"): 3.0}
+    assert pins == {"encode": 1.0}
 
 
 def test_decode_kernel_failure_falls_back_without_frame_loss(
-    force_pallas, monkeypatch
+    monkeypatch, counting
 ):
     bs = 1024
     rng = np.random.default_rng(2)
@@ -268,27 +306,28 @@ def test_decode_kernel_failure_falls_back_without_frame_loss(
     )
     assert [bytes(o) for o in out] == blocks  # every frame recovered
     assert crcs == [None, None]  # caller certifies those from its own bytes
+    batches, pins = _device_counters()
+    assert batches == {("decode", "failed"): 1.0}
+    assert pins == {}
 
 
-# ---------------------------------------------------------------------------
-# tlz_pallas plane stage: direct identity against the XLA math
-# ---------------------------------------------------------------------------
+def test_parity_kernel_failure_pins_host_and_counts(monkeypatch, counting):
+    from s3shuffle_tpu.coding import gf, gf_pallas
 
+    def boom(*a, **kw):
+        raise RuntimeError("mosaic lowering failed mid-kernel")
 
-@pytest.mark.parametrize("kind", ["text", "random", "zeros", "mixed"])
-def test_plane_kernel_identical_to_xla_math(kind):
-    import jax
-
-    bs = 512
-    n_groups = bs // tlz.GROUP
-    rng = np.random.default_rng(hash(kind) % 2**32)
-    batch = np.stack([
-        np.frombuffer(_make_block(kind, bs, rng), dtype=np.uint8)
-        for _ in range(2)
-    ])
-    dev = jax.device_put(batch)
-    got = tlz_pallas.encode_math_fn(n_groups)(dev)
-    want = tlz._encode_math(dev, n_groups)
-    assert len(got) == len(want) == 9
-    for g, w in zip(got, want):
-        assert np.array_equal(np.asarray(g), np.asarray(w))
+    monkeypatch.setenv("S3SHUFFLE_CODEC_RATE_GATE", "device")
+    monkeypatch.setattr(gf, "_device_broken", False)
+    monkeypatch.setattr(gf, "_DEVICE_MIN_BYTES", 0)
+    monkeypatch.setattr(gf, "_mesh_dispatcher", lambda: None)
+    monkeypatch.setattr(gf_pallas, "encode_groups_pallas", boom)
+    rng = np.random.default_rng(3)
+    chunks = rng.integers(0, 256, (2, 4, 128), dtype=np.uint8)
+    coefs = gf.parity_coefficients(2, 4)
+    for _ in range(2):  # the pin holds: the second batch never tries
+        got = gf.encode_groups(chunks, coefs)
+        assert np.array_equal(got, gf._encode_host(chunks, coefs))
+    batches, pins = _device_counters()
+    assert batches == {("parity", "failed"): 1.0}
+    assert pins == {"parity": 1.0}

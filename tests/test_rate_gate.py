@@ -20,9 +20,9 @@ from s3shuffle_tpu.ops import rates
 
 #: a table where every device kernel beats its host floor
 WINNING = {
-    "tpu_tlz_encode_pallas_mb_s": 900.0,
+    "tpu_tlz_encode_mb_s": 900.0,
     "tpu_tlz_decode_mb_s": 1004.2,
-    "tpu_tlz_decode_fused_pallas_mb_s": 900.0,
+    "tpu_tlz_decode_fused_mb_s": 900.0,
     "tpu_crc32c_pallas_mb_s": 2000.0,
     "tpu_gf_encode_mb_s": 1000.0,
 }
@@ -47,7 +47,7 @@ def _clean_gate(monkeypatch):
 @pytest.fixture
 def chip_attached(monkeypatch):
     """Pretend an accelerator answered the backend probe."""
-    monkeypatch.setattr(tpu_mod, "_probe_state", lambda: (True, True))
+    monkeypatch.setattr(tpu_mod, "_device_backend", lambda: True)
 
 
 # ---------------------------------------------------------------------------
@@ -79,14 +79,14 @@ def test_measured_host_when_chip_loses():
 
 def test_best_of_pallas_and_xla_represents_the_device():
     rates.set_rates_for_testing(
-        {"tpu_tlz_encode_mb_s": 3.6, "tpu_tlz_encode_pallas_mb_s": 900.0}
+        {"tpu_crc32c_mb_s": 40.5, "tpu_crc32c_pallas_mb_s": 2000.0}
     )
-    assert rates.decide("encode") == (True, "measured-device")
+    assert rates.decide("crc") == (True, "measured-device")
 
 
 def test_measured_host_field_overrides_default_floor():
     rates.set_rates_for_testing(
-        {"tpu_tlz_encode_pallas_mb_s": 100.0, "host_tlz_encode_mb_s": 50.0}
+        {"tpu_tlz_encode_mb_s": 100.0, "host_tlz_encode_mb_s": 50.0}
     )
     assert rates.decide("encode") == (True, "measured-device")
 
@@ -118,7 +118,7 @@ def test_fused_decode_wins_when_beating_effective_streaming():
     # streaming effective = 1/(1/1004.2 + 1/1500) ~= 601 MB/s
     rates.set_rates_for_testing(
         {"tpu_tlz_decode_mb_s": 1004.2,
-         "tpu_tlz_decode_fused_pallas_mb_s": 900.0}
+         "tpu_tlz_decode_fused_mb_s": 900.0}
     )
     assert rates.fused_decode_decision() == (True, "measured-device")
 
@@ -198,7 +198,7 @@ def test_wants_fused_decode_validation_three_regimes(chip_attached):
     # fused wins: decode on device AND fused beats effective streaming
     rates.set_rates_for_testing(
         {"tpu_tlz_decode_mb_s": 1004.2,
-         "tpu_tlz_decode_fused_pallas_mb_s": 900.0}
+         "tpu_tlz_decode_fused_mb_s": 900.0}
     )
     assert codec.wants_fused_decode_validation(POLY_CRC32C) is True
     # fused loses: decode stays device, validation stays streaming
