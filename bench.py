@@ -3193,7 +3193,7 @@ def observability_overhead(parts=None, repeats: int = 3, budget_pct: float = 3.0
         if mode == "trace":
             fd, tpath = tempfile.mkstemp(prefix="s3shuffle-obs-", suffix=".json")
             os.close(fd)
-            trace.enable(tpath, jax_annotations=False)
+            trace.enable(tpath)
             trace.configure_flight(ring=trace.FLIGHT_RING_DEFAULT)
             return tpath
         trace.disable()

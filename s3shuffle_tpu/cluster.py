@@ -122,7 +122,9 @@ def _run_reduce_task(args: Tuple[int, bytes, int, object]) -> bytes:
             tracker = meta
     handle = manager.register_shuffle(shuffle_id, dep)
     reader = manager.get_reader(handle, reduce_id, reduce_id + 1, tracker=tracker)
-    return pickle.dumps(list(reader.read()), protocol=pickle.HIGHEST_PROTOCOL)
+    with trace.span("task.reduce"):
+        records = list(reader.read())
+    return pickle.dumps(records, protocol=pickle.HIGHEST_PROTOCOL)
 
 
 def publish_snapshot(tracker, config: ShuffleConfig, shuffle_id: int):

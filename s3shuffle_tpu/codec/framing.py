@@ -22,12 +22,12 @@ from __future__ import annotations
 import io
 import struct
 import threading
-import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import BinaryIO, List, Optional, Tuple
 
 from s3shuffle_tpu.metrics import registry as _metrics
+from s3shuffle_tpu.utils import trace
 
 HEADER = struct.Struct("<BII")
 HEADER_SIZE = HEADER.size  # 9 bytes
@@ -155,10 +155,6 @@ class FrameCodec:
         return self.decompress_stream(io.BytesIO(data)).read()
 
 
-_H_ENCODE_BATCH = _metrics.REGISTRY.histogram(
-    "codec_encode_batch_seconds",
-    "Batch compress+frame call latency (device launch + host assembly)",
-)
 _C_ENCODE_BYTES = _metrics.REGISTRY.counter(
     "codec_encode_bytes_total", "Raw bytes through batch compress+frame calls"
 )
@@ -175,10 +171,6 @@ _C_FRAMES = _metrics.REGISTRY.counter(
     "codec_frames_total", "Frames emitted by codec output streams"
 )
 
-_H_DECODE_BATCH = _metrics.REGISTRY.histogram(
-    "codec_decode_batch_seconds",
-    "Batch decompress call latency (device launch + host parse/staging)",
-)
 _C_DECODE_BYTES = _metrics.REGISTRY.counter(
     "codec_decode_bytes_total",
     "Decoded (uncompressed) bytes out of batch decompress calls",
@@ -324,13 +316,12 @@ class CodecOutputStream(io.RawIOBase):
         """Compress+frame one batch (producer thread in sync mode, the shared
         encode thread in async mode). Returns (framed_bytes, crcs|None)."""
         mv = memoryview(buf)[: n_blocks * bs]
-        t0 = time.perf_counter_ns()
-        if self._checksum is not None and self._framed_fused is not None:
-            out, crcs = self._framed_fused(mv, n_blocks, bs)
-        else:
-            out, crcs = self._framed(mv, n_blocks, bs), None
+        with trace.span("codec.encode.batch", blocks=n_blocks):
+            if self._checksum is not None and self._framed_fused is not None:
+                out, crcs = self._framed_fused(mv, n_blocks, bs)
+            else:
+                out, crcs = self._framed(mv, n_blocks, bs), None
         if _metrics.enabled():
-            _H_ENCODE_BATCH.observe((time.perf_counter_ns() - t0) / 1e9)
             _C_ENCODE_BYTES.inc(n_blocks * bs)
         return out, crcs
 
@@ -355,7 +346,8 @@ class CodecOutputStream(io.RawIOBase):
         if _metrics.enabled():
             _G_ENCODE_INFLIGHT.dec(1)
         try:
-            out, crcs, n_frames = fut.result()
+            with trace.span("codec.encode.harvest"):
+                out, crcs, n_frames = fut.result()
         except BaseException:
             self._abort_inflight()
             raise
@@ -389,8 +381,9 @@ class CodecOutputStream(io.RawIOBase):
             buf = self._buf
             self._buf = bytearray(memoryview(buf)[cut:])
 
-            def job(b=buf, n=n_blocks):
-                out, crcs = self._encode_batch(b, n, bs)
+            def job(b=buf, n=n_blocks, ctx=trace.current_context()):
+                with trace.context(ctx):
+                    out, crcs = self._encode_batch(b, n, bs)
                 return out, crcs, n
 
             self._inflight.append((_get_encode_executor().submit(job), cut))
@@ -651,7 +644,13 @@ class CodecInputStream(io.RawIOBase):
                 break
         return run
 
-    def _decode_frames(self, frames):
+    def _decode_frames(self, frames, ctx=None):
+        """One decode job under the ``codec.decode.batch`` span, adopting
+        the submitting task's trace context ``ctx`` on a decode thread."""
+        with trace.context(ctx), trace.span("codec.decode.batch"):
+            return self._decode_run(frames)
+
+    def _decode_run(self, frames):
         """Decode an in-order run of frames sharing one codec_id into ONE
         contiguous chunk (fewer, bigger pieces crossing the stream stack ⇒
         fewer per-chunk copy calls). Runs on the consumer thread in sync
@@ -660,7 +659,6 @@ class CodecInputStream(io.RawIOBase):
         armed) lists ``(frame_len, frame_crc_or_None)`` per frame in order."""
         codec_id = frames[0][0]
         certs = [] if self._certify is not None else None
-        t0 = time.perf_counter_ns()
         if codec_id == 0:
             out = (
                 frames[0][1] if len(frames) == 1
@@ -671,7 +669,6 @@ class CodecInputStream(io.RawIOBase):
                     (HEADER_SIZE + len(p), None) for _c, p, _u in frames
                 )
             if _metrics.enabled():
-                _H_DECODE_BATCH.observe((time.perf_counter_ns() - t0) / 1e9)
                 _C_DECODE_BYTES.inc(len(out))
             return out, certs
         # route the whole run through its codec — the configured codec when
@@ -700,18 +697,18 @@ class CodecInputStream(io.RawIOBase):
         if certs is not None:
             from s3shuffle_tpu.ops.checksum import crc_combine, host_crc
 
-            for i, (_c, p, u) in enumerate(frames):
-                crc = crcs[i] if crcs is not None else None
-                if crc is not None:
-                    # frame = 9-byte header (host-hashed) + payload (fused)
-                    header = HEADER.pack(codec_id, u, len(p))
-                    crc = crc_combine(
-                        host_crc(header, self._fused_poly), crc, len(p),
-                        self._fused_poly,
-                    )
-                certs.append((HEADER_SIZE + len(p), crc))
+            with trace.span("codec.decode.emit"):
+                for i, (_c, p, u) in enumerate(frames):
+                    crc = crcs[i] if crcs is not None else None
+                    if crc is not None:
+                        # frame = 9-byte header (host-hashed) + payload (fused)
+                        header = HEADER.pack(codec_id, u, len(p))
+                        crc = crc_combine(
+                            host_crc(header, self._fused_poly), crc, len(p),
+                            self._fused_poly,
+                        )
+                    certs.append((HEADER_SIZE + len(p), crc))
         if _metrics.enabled():
-            _H_DECODE_BATCH.observe((time.perf_counter_ns() - t0) / 1e9)
             _C_DECODE_BYTES.inc(len(out))
         return out, certs
 
@@ -752,7 +749,9 @@ class CodecInputStream(io.RawIOBase):
             try:
                 run = self._read_run()
                 if run:
-                    fut = _get_decode_executor().submit(self._decode_frames, run)
+                    fut = _get_decode_executor().submit(
+                        self._decode_frames, run, trace.current_context()
+                    )
             except BaseException:
                 # the reservation is in neither _inflight nor _decoded yet —
                 # release here or the scan budget stays inflated for good
@@ -772,7 +771,8 @@ class CodecInputStream(io.RawIOBase):
         if _metrics.enabled():
             _G_DECODE_INFLIGHT.dec(1)
         try:
-            chunk, certs = fut.result()
+            with trace.span("codec.decode.harvest"):
+                chunk, certs = fut.result()
             self._apply_certs(certs)
         except BaseException:
             if reserved and self._budget is not None:

@@ -33,7 +33,6 @@ from typing import List
 import numpy as np
 
 from s3shuffle_tpu.codec.framing import CODEC_IDS, FrameCodec
-from s3shuffle_tpu.metrics import registry as _metrics
 from s3shuffle_tpu.ops import rates, tlz
 from s3shuffle_tpu.ops.checksum import (
     POLY_CRC32,
@@ -42,14 +41,9 @@ from s3shuffle_tpu.ops.checksum import (
     crc_combine,
     stage_right_aligned,
 )
+from s3shuffle_tpu.utils import trace
 
 logger = logging.getLogger("s3shuffle_tpu.codec.tpu")
-
-_H_ASSEMBLY = _metrics.REGISTRY.histogram(
-    "codec_assembly_seconds",
-    "Host payload-assembly seconds per device encode batch (metadata "
-    "packing + vectorized plane compaction; the chip does the rest)",
-)
 
 #: host CRC32C for the small header/metadata slices stitched around fused
 #: device CRCs (native C when built, Python table otherwise) — resolved once
@@ -322,18 +316,14 @@ class TpuCodec(FrameCodec):
         failures pins the instance to the host path (each retry would eat an
         exception + fallback per batch forever)."""
         if self._select_device("encode"):
-            timings: dict = {}
             try:
                 payloads, crc_info = tlz.encode_batch_device(
                     mv, n_blocks, block_size,
                     batch_blocks=self.batch_blocks, poly=poly,
-                    timings=timings,
                 )
                 self._device_failures = 0
                 self._device_ok()
                 rates.count_device_batch("encode", True)
-                if _metrics.enabled() and timings.get("assembly_s"):
-                    _H_ASSEMBLY.observe(timings["assembly_s"])
                 return payloads, crc_info
             except Exception:
                 rates.count_device_batch("encode", False)
@@ -378,41 +368,42 @@ class TpuCodec(FrameCodec):
         payloads, crc_info = self._encode_full_blocks(
             mv, n_blocks, block_size, POLY_CRC32C if want_crcs else None
         )
-        out = bytearray()
-        crcs: List | None = [] if crc_info is not None else None
-        if crc_info is not None:
-            block_crcs, lit_crcs, lit_lens = crc_info
-        for i, pl in enumerate(payloads):
-            if len(pl) >= block_size:  # framing raw escape
-                header = HEADER.pack(0, block_size, block_size)
-                out += header
-                out += mv[i * block_size : (i + 1) * block_size]
-                if crcs is not None:
-                    # stored bytes = header + RAW block; the raw-block CRC
-                    # came fused from the same launch as the encode planes
-                    crcs.append((
-                        crc_combine(
-                            _crc32c_host(header), int(block_crcs[i]),
-                            block_size, POLY_CRC32C,
-                        ),
-                        HEADER_SIZE + block_size,
-                    ))
-            else:
-                header = HEADER.pack(self.codec_id, block_size, len(pl))
-                out += header
-                out += pl
-                if crcs is not None:
-                    # stored bytes = header + metadata prefix + literal
-                    # plane; only the small prefix touches the host CRC
-                    lit_len = int(lit_lens[i])
-                    crcs.append((
-                        crc_combine(
-                            _crc32c_host(header + pl[: len(pl) - lit_len]),
-                            int(lit_crcs[i]), lit_len, POLY_CRC32C,
-                        ),
-                        HEADER_SIZE + len(pl),
-                    ))
-        return bytes(out), crcs
+        with trace.span("codec.encode.assemble"):
+            out = bytearray()
+            crcs: List | None = [] if crc_info is not None else None
+            if crc_info is not None:
+                block_crcs, lit_crcs, lit_lens = crc_info
+            for i, pl in enumerate(payloads):
+                if len(pl) >= block_size:  # framing raw escape
+                    header = HEADER.pack(0, block_size, block_size)
+                    out += header
+                    out += mv[i * block_size : (i + 1) * block_size]
+                    if crcs is not None:
+                        # stored bytes = header + RAW block; the raw-block CRC
+                        # came fused from the same launch as the encode planes
+                        crcs.append((
+                            crc_combine(
+                                _crc32c_host(header), int(block_crcs[i]),
+                                block_size, POLY_CRC32C,
+                            ),
+                            HEADER_SIZE + block_size,
+                        ))
+                else:
+                    header = HEADER.pack(self.codec_id, block_size, len(pl))
+                    out += header
+                    out += pl
+                    if crcs is not None:
+                        # stored bytes = header + metadata prefix + literal
+                        # plane; only the small prefix touches the host CRC
+                        lit_len = int(lit_lens[i])
+                        crcs.append((
+                            crc_combine(
+                                _crc32c_host(header + pl[: len(pl) - lit_len]),
+                                int(lit_crcs[i]), lit_len, POLY_CRC32C,
+                            ),
+                            HEADER_SIZE + len(pl),
+                        ))
+            return bytes(out), crcs
 
     def compress_framed(self, buf, n_blocks: int, block_size: int) -> bytes:
         """Contiguous-buffer fast path (framing.CodecOutputStream hook): the

@@ -135,9 +135,15 @@ KNOWN_METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "codec_kernel_compile_seconds": ("histogram", ("kernel",)),
     "codec_device_batches_total": ("counter", ("op", "outcome")),
     "codec_host_pins_total": ("counter", ("op",)),
+    # --- codec plane: device launch shapes and queue (ops/rates.py, fed at
+    # the ops/tlz.py launch sites) ---
+    "codec_launches_total": ("counter", ("op",)),
+    "codec_launch_rows_total": ("counter", ("op", "row")),
+    "codec_launches_ahead_total": ("counter", ("op",)),
     # --- trace plane: span shards, flight recorder, fleet telemetry, cost
     # (utils/trace.py, metadata/service.py, s3shuffle_tpu/costs.py) ---
     "trace_shard_bytes_total": ("counter", ()),
+    "trace_span_self_us_total": ("counter", ("span",)),
     "trace_shard_drops_total": ("counter", ("reason",)),
     "flight_dumps_total": ("counter", ("reason",)),
     "fleet_snapshot_age_seconds": ("gauge", ("worker",)),

@@ -51,12 +51,6 @@ _C_SELECTED = _metrics.REGISTRY.counter(
     "forced, env-device, env-host, gate-off)",
     labelnames=("path", "reason"),
 )
-_H_COMPILE = _metrics.REGISTRY.histogram(
-    "codec_kernel_compile_seconds",
-    "Cold-compile wall seconds per device codec kernel (first trace+lower "
-    "of each kernel shape; warm launches never appear here)",
-    labelnames=("kernel",),
-)
 
 _C_DEVICE_BATCHES = _metrics.REGISTRY.counter(
     "codec_device_batches_total",
@@ -80,6 +74,49 @@ def count_device_batch(op: str, ok: bool) -> None:
 def count_host_pin(op: str) -> None:
     if _metrics.enabled():
         _C_HOST_PINS.labels(op=op).inc()
+
+
+_C_LAUNCHES = _metrics.REGISTRY.counter(
+    "codec_launches_total",
+    "Device codec kernel launches by op (encode, decode); a batch whose "
+    "frames all go to the host decoder launches nothing",
+    labelnames=("op",),
+)
+_C_LAUNCH_ROWS = _metrics.REGISTRY.counter(
+    "codec_launch_rows_total",
+    "Rows of device codec launches by op; row=real carries a block for the "
+    "device, row=pad is bucket padding or a decode row left to the host",
+    labelnames=("op", "row"),
+)
+_C_LAUNCHES_AHEAD = _metrics.REGISTRY.counter(
+    "codec_launches_ahead_total",
+    "Sum over launches of the launches already dispatched and not yet "
+    "drained on the same device when each was dispatched, by op",
+    labelnames=("op",),
+)
+
+#: launches dispatched and not yet drained, per device, process-wide
+_queue_lock = threading.Lock()
+_queued: Dict[object, int] = {}
+
+
+def launch_dispatched(op: str, device, real: int, pad: int) -> None:
+    """One codec launch was dispatched to ``device`` (None: the default
+    device) with ``real`` + ``pad`` rows; pair with :func:`launch_drained`."""
+    with _queue_lock:
+        ahead = _queued.get(device, 0)
+        _queued[device] = ahead + 1
+    if _metrics.enabled():
+        _C_LAUNCHES.labels(op=op).inc()
+        _C_LAUNCH_ROWS.labels(op=op, row="real").inc(real)
+        _C_LAUNCH_ROWS.labels(op=op, row="pad").inc(pad)
+        _C_LAUNCHES_AHEAD.labels(op=op).inc(ahead)
+
+
+def launch_drained(device) -> None:
+    """A launch's outputs reached the host (or it was abandoned)."""
+    with _queue_lock:
+        _queued[device] -= 1
 
 
 #: cache filename shared with bench.py (kept in sync by convention; bench
@@ -392,20 +429,12 @@ def select_fused_decode(*, forced: bool = False) -> bool:
     return use
 
 
-def observe_compile(kernel: str, seconds: float) -> None:
-    """Record one cold-compile duration for a device codec kernel (the
-    kernel wrappers time their first call per shape)."""
-    if _metrics.enabled():
-        _H_COMPILE.labels(kernel=kernel).observe(seconds)
-
-
 def timed_first_call(kernel: str, fn):
     """Wrap a jitted kernel so its FIRST invocation (trace + lower + compile
-    + run) is timed into ``codec_kernel_compile_seconds{kernel}``. Warm
-    calls go straight through. One wrapper per compiled shape — callers
-    build these inside their per-shape lru caches."""
-    import time
-
+    + run) is timed by the ``codec.compile`` span, which observes
+    ``codec_kernel_compile_seconds{kernel}``. Warm calls go straight
+    through. One wrapper per compiled shape — callers build these inside
+    their per-shape lru caches."""
     state = {"cold": True}
     state_lock = threading.Lock()
 
@@ -415,9 +444,9 @@ def timed_first_call(kernel: str, fn):
             state["cold"] = False
         if not cold:
             return fn(*args, **kwargs)
-        t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
-        observe_compile(kernel, time.perf_counter() - t0)
-        return out
+        from s3shuffle_tpu.utils import trace
+
+        with trace.span("codec.compile", kernel=kernel):
+            return fn(*args, **kwargs)
 
     return wrapped

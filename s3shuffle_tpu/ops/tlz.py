@@ -579,7 +579,6 @@ def encode_batch_device(
     block_size: int,
     batch_blocks: Optional[int] = None,
     poly: Optional[int] = None,
-    timings: Optional[dict] = None,
 ):
     """Encode ``n_blocks`` FULL blocks held contiguously in ``buf`` on the
     device with FIXED-shape precompiled launches of ``batch_blocks`` rows
@@ -594,8 +593,11 @@ def encode_batch_device(
     framing raw-escape branch) and ``lit_crcs[i]``/``lit_lens[i]`` cover
     payload i's literal-plane bytes — callers stitch the small
     header/metadata CRCs around them with ``crc_combine``. Without ``poly``:
-    ``(payloads, None)``. ``timings`` (optional dict) accumulates
-    ``assembly_s``: the host-side assembly seconds within the call."""
+    ``(payloads, None)``. Each launch is timed by the ``codec.encode.stage``
+    / ``codec.encode.device`` / ``codec.encode.assemble`` spans."""
+    from s3shuffle_tpu.ops import rates
+    from s3shuffle_tpu.utils import trace
+
     _check_block_size(block_size)
     n_groups = block_size // GROUP
     cap = max(1, batch_blocks or n_blocks)
@@ -615,22 +617,22 @@ def encode_batch_device(
     # single-device op pattern this function always had.
     disp = _mesh_dispatcher()
     window = disp.max_inflight() if disp is not None else 0
-    pending: List[tuple] = []  # (launch outputs, real rows, lane)
+    pending: List[tuple] = []  # (launch outputs, real rows, lane, device)
 
     def _drain_oldest(backpressure: bool) -> None:
-        outs, n_real, slot = pending.pop(0)
+        outs, n_real, slot, dev = pending.pop(0)
         t0 = _time.perf_counter()
-        arrs = tuple(np.asarray(x) for x in outs)
+        try:
+            with trace.span("codec.encode.device"):
+                arrs = tuple(np.asarray(x) for x in outs)
+        finally:
+            rates.launch_drained(dev)
         if disp is not None:
             disp.release(slot)
             if backpressure:
                 disp.observe_wait(_time.perf_counter() - t0)
-        t1 = _time.perf_counter()
-        payloads.extend(_assemble_batch(arrs[:9], n_real, n_groups))
-        if timings is not None:
-            timings["assembly_s"] = (
-                timings.get("assembly_s", 0.0) + _time.perf_counter() - t1
-            )
+        with trace.span("codec.encode.assemble"):
+            payloads.extend(_assemble_batch(arrs[:9], n_real, n_groups))
         if crc_parts is not None:
             crc_parts.append(
                 (arrs[9][:n_real], arrs[10][:n_real],
@@ -647,40 +649,44 @@ def encode_batch_device(
                 # device_put-aliased staging plane — drain until the lane is
                 # free before restaging on it
                 _drain_oldest(True)
-            if rows == e - s:
-                staged = np.frombuffer(
-                    mv[s * block_size : e * block_size], dtype=np.uint8
-                ).reshape(rows, block_size)
-            else:
-                staged = _staging.get(rows, block_size, slot)
-                flat = staged.reshape(-1)
-                used = (e - s) * block_size
-                flat[:used] = np.frombuffer(
-                    mv[s * block_size : e * block_size], dtype=np.uint8
-                )
-                flat[used:] = 0  # deterministic pad rows (outputs discarded)
-            with warnings.catch_warnings():
-                # the donated staging buffer may not be aliasable on every
-                # backend (XLA:CPU uint8 staging) — jax warns per
-                # compilation; an expected no-op for OUR launch, suppressed
-                # only around it so the host application's own donation
-                # warnings stay visible
-                warnings.filterwarnings(
-                    "ignore", message="Some donated buffers were not usable"
-                )
-                outs = _batch_kernel(rows, n_groups, poly)(
-                    jax.device_put(staged, disp.device(slot))
-                    if disp is not None
-                    else jax.device_put(staged)
-                )
-            pending.append((outs, e - s, slot))
+            with trace.span("codec.encode.stage"):
+                if rows == e - s:
+                    staged = np.frombuffer(
+                        mv[s * block_size : e * block_size], dtype=np.uint8
+                    ).reshape(rows, block_size)
+                else:
+                    staged = _staging.get(rows, block_size, slot)
+                    flat = staged.reshape(-1)
+                    used = (e - s) * block_size
+                    flat[:used] = np.frombuffer(
+                        mv[s * block_size : e * block_size], dtype=np.uint8
+                    )
+                    flat[used:] = 0  # deterministic pad rows (outputs discarded)
+                dev = disp.device(slot) if disp is not None else None
+                with warnings.catch_warnings():
+                    # the donated staging buffer may not be aliasable on every
+                    # backend (XLA:CPU uint8 staging) — jax warns per
+                    # compilation; an expected no-op for OUR launch, suppressed
+                    # only around it so the host application's own donation
+                    # warnings stay visible
+                    warnings.filterwarnings(
+                        "ignore", message="Some donated buffers were not usable"
+                    )
+                    outs = _batch_kernel(rows, n_groups, poly)(
+                        jax.device_put(staged, dev)
+                        if dev is not None
+                        else jax.device_put(staged)
+                    )
+                rates.launch_dispatched("encode", dev, e - s, rows - (e - s))
+            pending.append((outs, e - s, slot, dev))
             while len(pending) > window:
                 _drain_oldest(True)
         while pending:
             _drain_oldest(False)
     except BaseException:
-        if disp is not None:
-            for _outs, _n, slot in pending:
+        for _outs, _n, slot, dev in pending:
+            rates.launch_drained(dev)
+            if disp is not None:
                 disp.release(slot)
         raise
     if crc_parts is None:
@@ -1592,7 +1598,6 @@ def decode_batch_device(
     block_size: int,
     batch_rows: Optional[int] = None,
     poly: Optional[int] = None,
-    timings: Optional[dict] = None,
 ):
     """Batched device decode of v2 TLZ payloads with FIXED-shape precompiled
     launches of ``batch_rows`` rows (partial batches pad to a power-of-two
@@ -1606,9 +1611,13 @@ def decode_batch_device(
     header/metadata prefix with ``crc_combine``): returns
     ``(blocks, payload_crcs)`` where ``payload_crcs[i]`` is the CRC of
     ``payloads[i]`` or None for fallback rows (callers hash those on the
-    host). Without ``poly``: ``(blocks, None)``. ``timings`` (optional dict)
-    accumulates ``parse_s``: host-side parse/staging seconds."""
+    host). Without ``poly``: ``(blocks, None)``. Each launch is timed by the
+    ``codec.decode.parse`` / ``codec.decode.device`` / ``codec.decode.emit``
+    spans."""
     import time as _time
+
+    from s3shuffle_tpu.ops import rates
+    from s3shuffle_tpu.utils import trace
 
     n_groups = block_size // GROUP
     b = len(payloads)
@@ -1630,110 +1639,115 @@ def decode_batch_device(
     # launch→drain→emit sequence synchronous on the default device.
     disp = _mesh_dispatcher()
     window = disp.max_inflight() if disp is not None else 0
-    pending: List[tuple] = []  # (launch outputs, parsed rows, start, lane)
+    pending: List[tuple] = []  # (launch outputs, parsed rows, start, lane, device)
 
     def _drain_oldest(backpressure: bool) -> None:
-        outs, prows, s0, slot = pending.pop(0)
+        outs, prows, s0, slot, dev = pending.pop(0)
         t0 = _time.perf_counter()
-        if poly is None:
-            decoded = np.asarray(outs)
-            raw_crcs = None
-        else:
-            decoded = np.asarray(outs[0])
-            raw_crcs = np.asarray(outs[1])
+        try:
+            with trace.span("codec.decode.device"):
+                if poly is None:
+                    decoded = np.asarray(outs)
+                    raw_crcs = None
+                else:
+                    decoded = np.asarray(outs[0])
+                    raw_crcs = np.asarray(outs[1])
+        finally:
+            rates.launch_drained(dev)
         if disp is not None:
             disp.release(slot)
             if backpressure:
                 disp.observe_wait(_time.perf_counter() - t0)
-        for j, row in enumerate(prows):
-            if row is None:
-                continue
-            out[s0 + j] = decoded[j, : ulens[s0 + j]].tobytes()
-            if raw_crcs is not None:
-                nl, lit_off = row[6], row[7]
-                lit_len = nl * GROUP
-                payload = payloads[s0 + j]
-                # stored payload = prefix (host-hashed, small) + literal
-                # plane (CRC'd in the launch, fixed up for length)
-                lit_crc = int(raw_crcs[j]) ^ int(zero[lit_len])
-                crcs[s0 + j] = crc_combine(
-                    host_crc(payload[: len(payload) - lit_len], poly),
-                    lit_crc, lit_len, poly,
-                )
+        with trace.span("codec.decode.emit"):
+            for j, row in enumerate(prows):
+                if row is None:
+                    continue
+                out[s0 + j] = decoded[j, : ulens[s0 + j]].tobytes()
+                if raw_crcs is not None:
+                    nl, lit_off = row[6], row[7]
+                    lit_len = nl * GROUP
+                    payload = payloads[s0 + j]
+                    # stored payload = prefix (host-hashed, small) + literal
+                    # plane (CRC'd in the launch, fixed up for length)
+                    lit_crc = int(raw_crcs[j]) ^ int(zero[lit_len])
+                    crcs[s0 + j] = crc_combine(
+                        host_crc(payload[: len(payload) - lit_len], poly),
+                        lit_crc, lit_len, poly,
+                    )
 
     try:
         for s in range(0, b, cap):
             e = min(b, s + cap)
-            t0 = _time.perf_counter()
-            rows, fallback = _parse_batch_v2(
-                payloads[s:e], ulens[s:e], n_groups
-            )
-            if timings is not None:
-                timings["parse_s"] = (
-                    timings.get("parse_s", 0.0) + _time.perf_counter() - t0
+            with trace.span("codec.decode.parse"):
+                rows, fallback = _parse_batch_v2(
+                    payloads[s:e], ulens[s:e], n_groups
                 )
-            for j in sorted(fallback):
-                out[s + j] = decode_payload_numpy(payloads[s + j], ulens[s + j])
-            if len(fallback) == e - s:  # nothing device-shaped (e.g. a reader
-                # whose block_size differs from the writer's) — skip the kernel
-                continue
-            launch_rows = _bucket_rows(e - s, cap)
-            slot = disp.acquire("decode") if disp is not None else 0
-            while any(p[3] == slot for p in pending):
-                # the lane's previous launch may still be reading its
-                # device_put-aliased staging planes — drain until the lane
-                # is free before zeroing/refilling them
-                _drain_oldest(True)
-            staging = _decode_staging.get(launch_rows, n_groups, slot)
-            is_match, is_cont, is_split, offs, ks, lits, nlits = staging
-            for arr in staging:
-                arr[...] = 0  # deterministic pad + fallback rows
-            for j in range(e - s):
-                row = rows[j]
-                if row is None:
+                for j in sorted(fallback):
+                    out[s + j] = decode_payload_numpy(payloads[s + j], ulens[s + j])
+                if len(fallback) == e - s:  # nothing device-shaped (e.g. a
+                    # reader whose block_size differs from the writer's) —
+                    # skip the kernel
                     continue
-                m, c, sp, dist_vals, kv, l, nl, _lit_off = row
-                is_match[j] = m
-                is_cont[j] = c
-                is_split[j] = sp
-                offs[j, : len(dist_vals)] = dist_vals
-                ks[j, : len(kv)] = kv
-                lits[j, :nl] = l.reshape(nl, GROUP)
-                nlits[j] = nl
-            dev = disp.device(slot) if disp is not None else None
+                launch_rows = _bucket_rows(e - s, cap)
+                slot = disp.acquire("decode") if disp is not None else 0
+                while any(p[3] == slot for p in pending):
+                    # the lane's previous launch may still be reading its
+                    # device_put-aliased staging planes — drain until the
+                    # lane is free before zeroing/refilling them
+                    _drain_oldest(True)
+                staging = _decode_staging.get(launch_rows, n_groups, slot)
+                is_match, is_cont, is_split, offs, ks, lits, nlits = staging
+                for arr in staging:
+                    arr[...] = 0  # deterministic pad + fallback rows
+                for j in range(e - s):
+                    row = rows[j]
+                    if row is None:
+                        continue
+                    m, c, sp, dist_vals, kv, l, nl, _lit_off = row
+                    is_match[j] = m
+                    is_cont[j] = c
+                    is_split[j] = sp
+                    offs[j, : len(dist_vals)] = dist_vals
+                    ks[j, : len(kv)] = kv
+                    lits[j, :nl] = l.reshape(nl, GROUP)
+                    nlits[j] = nl
+                dev = disp.device(slot) if disp is not None else None
 
-            def _put(arr, dev=dev):
-                return (
-                    jax.device_put(arr, dev)
-                    if dev is not None
-                    else jax.device_put(arr)
-                )
+                def _put(arr, dev=dev):
+                    return (
+                        jax.device_put(arr, dev)
+                        if dev is not None
+                        else jax.device_put(arr)
+                    )
 
-            with warnings.catch_warnings():
-                # donated staging may not be aliasable on every backend
-                # (XLA:CPU bool/uint8 staging) — an expected no-op for OUR
-                # launch; suppressed only around it (see encode_batch_device)
-                warnings.filterwarnings(
-                    "ignore", message="Some donated buffers were not usable"
-                )
-                if poly is None:
-                    outs = _decode_batch_kernel(launch_rows, n_groups, None)(
-                        _put(is_match), _put(is_cont), _put(is_split),
-                        _put(offs), _put(ks), _put(lits),
+                with warnings.catch_warnings():
+                    # donated staging may not be aliasable on every backend
+                    # (XLA:CPU bool/uint8 staging) — an expected no-op for OUR
+                    # launch; suppressed only around it (see encode_batch_device)
+                    warnings.filterwarnings(
+                        "ignore", message="Some donated buffers were not usable"
                     )
-                else:
-                    outs = _decode_batch_kernel(launch_rows, n_groups, poly)(
-                        _put(is_match), _put(is_cont), _put(is_split),
-                        _put(offs), _put(ks), _put(lits), _put(nlits),
-                    )
-            pending.append((outs, rows[: e - s], s, slot))
+                    if poly is None:
+                        outs = _decode_batch_kernel(launch_rows, n_groups, None)(
+                            _put(is_match), _put(is_cont), _put(is_split),
+                            _put(offs), _put(ks), _put(lits),
+                        )
+                    else:
+                        outs = _decode_batch_kernel(launch_rows, n_groups, poly)(
+                            _put(is_match), _put(is_cont), _put(is_split),
+                            _put(offs), _put(ks), _put(lits), _put(nlits),
+                        )
+                real = e - s - len(fallback)
+                rates.launch_dispatched("decode", dev, real, launch_rows - real)
+            pending.append((outs, rows[: e - s], s, slot, dev))
             while len(pending) > window:
                 _drain_oldest(True)
         while pending:
             _drain_oldest(False)
     except BaseException:
-        if disp is not None:
-            for _outs, _r, _s, slot in pending:
+        for _outs, _r, _s, slot, dev in pending:
+            rates.launch_drained(dev)
+            if disp is not None:
                 disp.release(slot)
         raise
     return out, crcs

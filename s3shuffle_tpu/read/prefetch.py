@@ -34,7 +34,7 @@ from typing import Iterator, List, Optional, Tuple
 from s3shuffle_tpu.metrics import registry as _metrics
 from s3shuffle_tpu.read.block_stream import BlockStream
 from s3shuffle_tpu.tuning.controller import Controller
-from s3shuffle_tpu.utils import racewitness
+from s3shuffle_tpu.utils import racewitness, trace
 from s3shuffle_tpu.utils.io import read_up_to as _read_up_to
 
 logger = logging.getLogger("s3shuffle_tpu.read")
@@ -241,6 +241,8 @@ class BufferedPrefetchIterator:
         racewitness.watch_shared(
             self, ("_buffers_in_flight", "_active_fetches", "_completed")
         )
+        #: the reduce task's trace context, adopted by every fill thread
+        self._trace_ctx = trace.current_context()
         self._configure_threads()
 
     def _warn_backstop(self, which: str, detail: str) -> None:
@@ -276,6 +278,10 @@ class BufferedPrefetchIterator:
             # Threads with id ≥ desired retire themselves in _prefetch_loop.
 
     def _prefetch_loop(self, thread_id: int) -> None:
+        with trace.context(self._trace_ctx):
+            self._fill_blocks()
+
+    def _fill_blocks(self) -> None:
         me = threading.current_thread()
         while True:
             with self._lock:
@@ -347,7 +353,6 @@ class BufferedPrefetchIterator:
                     self._buffers_in_flight += bsize
             try:
                 from s3shuffle_tpu.skew import tracked_get
-                from s3shuffle_tpu.utils import trace
 
                 t0 = time.perf_counter_ns()
                 with trace.span("read.prefetch", block=block.name, budget=bsize):
@@ -495,6 +500,13 @@ class BufferedPrefetchIterator:
         return self
 
     def __next__(self) -> PrefetchedBlockStream:
+        # read_prefetch_wait_seconds comes from _next_completed's own clock,
+        # which the thread predictor needs with metrics off; this span only
+        # names the wait for the profiler and counts its self time
+        with trace.span("read.prefetch_wait"):
+            return self._next_completed()
+
+    def _next_completed(self) -> PrefetchedBlockStream:
         t0 = time.perf_counter_ns()
         with self._lock:
             while not self._completed:

@@ -38,6 +38,7 @@ from s3shuffle_tpu.read.block_iterator import ReadableBlockId
 from s3shuffle_tpu.read.checksum_stream import ChecksumValidationStream
 from s3shuffle_tpu.sorter import ExternalSorter
 from s3shuffle_tpu.storage.dispatcher import Dispatcher
+from s3shuffle_tpu.utils import trace
 
 logger = logging.getLogger("s3shuffle_tpu.read")
 
@@ -248,8 +249,6 @@ class ShuffleReader:
         )
 
     def read(self) -> Iterator[Tuple[Any, Any]]:
-        from s3shuffle_tpu.utils import trace
-
         trace.count("read.tasks")
         if self.dep.serializer.supports_batches:
             if self.dep.aggregator is None:
@@ -360,7 +359,9 @@ class ShuffleReader:
         for prefetched in prefetcher:
             stream = self._wrapped_stream(prefetched, budget=budget)
             try:
-                for chunk in self.dep.serializer.new_chunk_read_stream(stream):  # type: ignore[arg-type]
+                for chunk in _deserialized(
+                    self.dep.serializer.new_chunk_read_stream(stream)  # type: ignore[arg-type]
+                ):
                     self.metrics.records_read += pending
                     count_fallback_rows("read", pending)
                     pending = len(chunk)
@@ -386,7 +387,7 @@ class ShuffleReader:
         for prefetched in prefetcher:
             stream = self._wrapped_stream(prefetched, budget=budget)
             try:
-                for batch in self.dep.serializer.new_batch_read_stream(stream):
+                for batch in _deserialized(self.dep.serializer.new_batch_read_stream(stream)):
                     self.metrics.records_read += batch.n
                     count_plane_rows("read", batch.n)
                     yield batch
@@ -456,14 +457,20 @@ class ShuffleReader:
 
         sorter = BatchSorter(spill_bytes=self.dispatcher.config.sorter_spill_bytes)
         for batch in self.read_batches():
-            sorter.add(batch)
+            with trace.span("agg.sort"):
+                sorter.add(batch)
         return sorter
 
     def read_result_batches(self):
         """Fully-columnar terminal read: the reduce output as a list of
         RecordBatches (ordering applied when the dependency asks for natural
         byte ordering). The columnar sibling of :meth:`read` for callers that
-        stay in batch land (bench, device repartition)."""
+        stay in batch land (bench, device repartition). The whole call is
+        the ``task.reduce`` span."""
+        with trace.span("task.reduce"):
+            return self._read_result_batches()
+
+    def _read_result_batches(self):
         from s3shuffle_tpu.batch import RecordBatch
         from s3shuffle_tpu.dependency import natural_key
 
@@ -491,6 +498,21 @@ class ShuffleReader:
         if self.dep.key_ordering is None:
             return list(self.read_batches())
         if self.dep.key_ordering is natural_key:
-            return list(self._fed_batch_sorter().sorted_batches())
+            sorter = self._fed_batch_sorter()
+            with trace.span("agg.sort"):
+                return list(sorter.sorted_batches())
         return fallback()
+
+
+def _deserialized(stream):
+    """The serializer's batches (or record chunks) of one block stream, each
+    pull under ``record.deserialize``: the decode window's harvest, inside
+    it, is its own span."""
+    it = iter(stream)
+    while True:
+        with trace.span("record.deserialize"):
+            item = next(it, None)
+        if item is None:
+            return
+        yield item
 

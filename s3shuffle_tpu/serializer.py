@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import pickle
 import struct
-import time
 from typing import Any, BinaryIO, Iterable, Iterator, Optional, Tuple
 
 from s3shuffle_tpu.metrics import registry as _metrics
@@ -43,11 +42,6 @@ _C_FALLBACK = _metrics.REGISTRY.counter(
     "columnar plane",
     labelnames=("site",),
 )
-_H_PARTITION = _metrics.REGISTRY.histogram(
-    "record_partition_seconds",
-    "Vectorized partition-assignment + stable-group pass latency per "
-    "columnar chunk (map side)",
-)
 
 
 def _count_frame(column: bool, plane: str) -> None:
@@ -66,17 +60,6 @@ def count_plane_rows(plane: str, rows: int) -> None:
     writers' partition/route pass and the reader's batch consumers."""
     if rows and _metrics.enabled():
         _C_ROWS.labels(plane=plane).inc(rows)
-
-
-def observe_partition_pass(t0_ns: int, rows: int) -> None:
-    """Map-writer hook: one vectorized partition/group pass finished.
-    ``t0_ns`` is the writer's ``perf_counter_ns`` taken iff metrics were
-    enabled (0 skips); ``rows`` feeds the write-plane row counter (pass 0
-    for passes whose rows were already counted, e.g. spill-time re-grouping
-    of buffered batches)."""
-    if t0_ns:
-        _H_PARTITION.observe((time.perf_counter_ns() - t0_ns) / 1e9)
-        count_plane_rows("write", rows)
 
 
 def count_fallback_rows(site: str, rows: int) -> None:

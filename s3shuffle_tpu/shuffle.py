@@ -30,6 +30,7 @@ from s3shuffle_tpu.dependency import (
 )
 from s3shuffle_tpu.manager import ShuffleManager
 from s3shuffle_tpu.serializer import Serializer
+from s3shuffle_tpu.utils import trace
 
 logger = logging.getLogger("s3shuffle_tpu.context")
 
@@ -93,7 +94,8 @@ class ShuffleContext:
             reader = self.manager.get_reader(handle, reduce_id, reduce_id + 1)
             if materialize == "batches":
                 return reader.read_result_batches()
-            return list(reader.read())
+            with trace.span("task.reduce"):
+                return list(reader.read())
 
         with ThreadPoolExecutor(max_workers=self.num_workers) as pool:
             list(pool.map(map_task, enumerate(input_partitions)))
@@ -293,7 +295,8 @@ class ShuffleContext:
         outputs: List[List[Tuple[bytes, bytes]]] = []
         for p in range(partitioner.num_partitions):
             reader = self.manager.get_reader(handle, p, p + 1)
-            outputs.append(list(reader.read()))
+            with trace.span("task.reduce"):
+                outputs.append(list(reader.read()))
         if cleanup:
             self.manager.unregister_shuffle(shuffle_id)
         return outputs, used_mesh

@@ -18,11 +18,34 @@ Kinds:
 Naming follows ``<plane>.<operation>``; the plane prefix is what the
 critical-path analyzer (``tools/critical_path.py``) buckets blame by, so a
 new span name lands in the right blame category by construction.
+
+Every span's self time also lands in the registry counter
+``trace_span_self_us_total{span}`` (utils/trace.py). The spans at the layer
+boundaries of a map task (``task.map`` → ``record.partition``,
+``record.serialize``, ``codec.encode.harvest``, ``write.commit``) and of a
+reduce task (``task.reduce`` → ``read.prefetch_wait``,
+``record.deserialize``, ``codec.decode.harvest``, ``agg.sort``), and the
+per-launch spans on the codec threads (``codec.encode.batch`` →
+``stage``/``device``/``assemble``; ``codec.decode.batch`` →
+``parse``/``device``/``emit``) are what the benchmark's per-layer metrics
+read. No span sits inside a per-record or per-block loop.
 """
 
 #: name -> kind ("span" | "counter"); pure literal, AST-parsed by lint
 KNOWN_SPANS = {
+    "agg.sort": "span",
+    "codec.compile": "span",
     "codec.compress_batch": "span",
+    "codec.decode.batch": "span",
+    "codec.decode.device": "span",
+    "codec.decode.emit": "span",
+    "codec.decode.harvest": "span",
+    "codec.decode.parse": "span",
+    "codec.encode.assemble": "span",
+    "codec.encode.batch": "span",
+    "codec.encode.device": "span",
+    "codec.encode.harvest": "span",
+    "codec.encode.stage": "span",
     "driver.collect": "span",
     "driver.compact": "span",
     "driver.job": "span",
@@ -34,8 +57,14 @@ KNOWN_SPANS = {
     "read.chunked_prefill": "span",
     "read.index_prefetch": "span",
     "read.prefetch": "span",
+    "read.prefetch_wait": "span",
     "read.tasks": "counter",
+    "record.deserialize": "span",
+    "record.partition": "span",
+    "record.serialize": "span",
     "storage.op": "span",
+    "task.map": "span",
+    "task.reduce": "span",
     "witness.violation": "span",
     "worker.drain": "span",
     "worker.task": "span",

@@ -8,8 +8,19 @@ profiler stack (uber jvm-profiler → InfluxDB → Grafana; examples/README.md:
 TPU-native analog of (c): an in-process tracer that records **spans**
 (name, thread, start, duration, attributes) and **counters**, exports them as
 Chrome trace-event JSON (loadable in chrome://tracing or Perfetto), and
-forwards span boundaries to ``jax.profiler.TraceAnnotation`` so host-side
-spans line up with device timelines in XProf captures.
+forwards span boundaries to ``jax.profiler.TraceAnnotation`` (whenever
+``jax`` is already imported; this module never imports it) so host-side
+spans line up with device timelines in profiler captures.
+
+**Self time.** Every span measures its duration minus the same-thread child
+spans it covers, and adds that to the registry counter
+``trace_span_self_us_total{span}`` in integer microseconds whenever the
+metrics registry is enabled, Chrome tracing on or off. A child on another
+thread (an executor job that adopted the task's :class:`context`) never
+subtracts from its parent: the per-layer self times then sum to the work
+each thread did, not to wall time. A span named in ``_SPAN_HISTOGRAMS`` also
+observes its whole duration into that latency histogram, so no call site
+keeps a second clock for the same interval.
 
 Distributed semantics (the trace *plane*):
 
@@ -29,9 +40,10 @@ Distributed semantics (the trace *plane*):
   rename, with partial buffers dumped by the atexit hook.
 
 Zero overhead when disabled: ``span()`` returns a shared no-op context
-manager unless tracing was enabled via :func:`enable` or the
-``S3SHUFFLE_TRACE`` env var (set to the output path, or ``1`` for
-``s3shuffle_trace.json``).
+manager unless the metrics registry is enabled or tracing was enabled via
+:func:`enable` or the ``S3SHUFFLE_TRACE`` env var (set to the output path,
+or ``1`` for ``s3shuffle_trace.json``). Chrome events are recorded only
+under :func:`enable`.
 
 **Flight recorder** (always on, independent of the enable flag): a bounded
 ring of recent records — explicit :func:`flight_record` milestones plus, when
@@ -52,6 +64,7 @@ import itertools
 import json
 import logging
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, Iterable, List, Optional
@@ -65,13 +78,59 @@ _C_FLIGHT_DUMPS = _metrics.REGISTRY.counter(
     "Flight-recorder postmortem dumps written, by trigger reason",
     labelnames=("reason",),
 )
+_C_SPAN_SELF = _metrics.REGISTRY.counter(
+    "trace_span_self_us_total",
+    "Span self time (duration minus the same-thread child spans it covers), "
+    "integer microseconds, by span name",
+    labelnames=("span",),
+)
+
+#: spans whose whole duration is also a registry histogram, observed at span
+#: exit (one clock per interval: the span's); a labelled histogram takes its
+#: label values from the span's arguments
+_SPAN_HISTOGRAMS = {
+    "record.partition": _metrics.REGISTRY.histogram(
+        "record_partition_seconds",
+        "Vectorized partition-assignment + stable-group pass latency per "
+        "columnar chunk (map side)",
+    ),
+    "record.serialize": _metrics.REGISTRY.histogram(
+        "write_serialize_seconds",
+        "Serializer→codec emission latency per record.serialize span (a "
+        "partition's rows, or one chunk's rows across partitions)",
+    ),
+    "write.commit": _metrics.REGISTRY.histogram(
+        "write_commit_seconds",
+        "Map-output commit latency (drain + serialize + upload + index)",
+    ),
+    "codec.encode.batch": _metrics.REGISTRY.histogram(
+        "codec_encode_batch_seconds",
+        "Batch compress+frame call latency (device launch + host assembly)",
+    ),
+    "codec.encode.assemble": _metrics.REGISTRY.histogram(
+        "codec_assembly_seconds",
+        "Host assembly seconds of device encode output: payloads per launch, "
+        "then frames and CRC stitching per batch",
+    ),
+    "codec.decode.batch": _metrics.REGISTRY.histogram(
+        "codec_decode_batch_seconds",
+        "Batch decompress call latency (device launch + host parse/staging)",
+    ),
+    "codec.compile": _metrics.REGISTRY.histogram(
+        "codec_kernel_compile_seconds",
+        "Cold-compile wall seconds per device codec kernel (first trace+lower "
+        "of each kernel shape; warm launches never appear here)",
+        labelnames=("kernel",),
+    ),
+}
 
 _lock = threading.Lock()
 _events: List[dict] = []
 _counters: Dict[str, float] = {}
 _enabled = False
 _path: Optional[str] = None
-_use_jax_annotations = False
+#: ``jax.profiler.TraceAnnotation`` once jax is imported by someone else
+_annotation_cls = None
 
 #: wall-clock anchor: spans time with ``perf_counter`` (monotonic — a span
 #: can never have negative duration under clock steps) and this per-process
@@ -99,16 +158,28 @@ def new_trace_id() -> str:
     return os.urandom(8).hex()
 
 
+class _Remote:
+    """A frame adopted from another thread or process: causal ids only, no
+    timing, so nothing on this thread subtracts from it."""
+
+    __slots__ = ("trace_id", "span_id")
+
+    def __init__(self, trace_id: str, span_id: str):
+        self.trace_id = trace_id
+        self.span_id = span_id
+
+
 def current_context() -> Optional[Dict[str, str]]:
     """The injectable causal context of the innermost open span on this
-    thread (``{"trace_id", "parent_id"}``), or None outside any span. The
-    driver stamps this into task descriptors; the worker adopts it with
+    thread (``{"trace_id", "parent_id"}``), or None outside any span or when
+    Chrome tracing is off (spans then carry no ids). ``DistributedDriver``
+    stamps this into task descriptors; the worker adopts it with
     :class:`context`."""
     frames = _frames()
-    if not frames:
+    if not frames or not frames[-1].span_id:
         return None
-    trace_id, span_id = frames[-1]
-    return {"trace_id": trace_id, "parent_id": span_id}
+    top = frames[-1]
+    return {"trace_id": top.trace_id, "parent_id": top.span_id}
 
 
 class context:
@@ -126,8 +197,8 @@ class context:
     def __enter__(self):
         ctx = self._ctx
         if ctx and ctx.get("trace_id") and ctx.get("parent_id"):
-            _frames().append((str(ctx["trace_id"]), str(ctx["parent_id"])))
-            self._pushed = True
+            self._pushed = _Remote(str(ctx["trace_id"]), str(ctx["parent_id"]))
+            _frames().append(self._pushed)
         return self
 
     def __exit__(self, *exc) -> None:
@@ -141,14 +212,13 @@ def _maybe_enable_from_env() -> None:
         enable("s3shuffle_trace.json" if val == "1" else val)
 
 
-def enable(path: str, jax_annotations: bool = True) -> None:
-    """Start recording; the trace file is written at :func:`flush` (also
-    registered atexit)."""
-    global _enabled, _path, _use_jax_annotations
+def enable(path: str) -> None:
+    """Start recording Chrome events; the trace file is written at
+    :func:`flush` (also registered atexit)."""
+    global _enabled, _path
     with _lock:
         _enabled = True
         _path = path
-        _use_jax_annotations = jax_annotations
 
 
 def disable() -> None:
@@ -163,6 +233,7 @@ def enabled() -> bool:
 
 class _NoopSpan:
     __slots__ = ()
+    seconds = 0.0
 
     def __enter__(self):
         return self
@@ -174,9 +245,21 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` when jax is already in
+    ``sys.modules`` (never imported from here), else None."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        _annotation_cls = getattr(profiler, "TraceAnnotation", None)
+    return _annotation_cls
+
+
 class _Span:
     __slots__ = (
-        "name", "args", "trace_id", "span_id", "parent_id", "_start", "_jax_ctx",
+        "name", "args", "trace_id", "span_id", "parent_id", "seconds",
+        "_start", "_child_ns", "_parent", "_ann",
     )
 
     def __init__(self, name: str, args: Dict[str, Any]):
@@ -185,37 +268,55 @@ class _Span:
         self.trace_id = ""
         self.span_id = ""
         self.parent_id: Optional[str] = None
+        self.seconds = 0.0  # the span's duration, once it has exited
         self._start = 0
-        self._jax_ctx = None
+        self._child_ns = 0
+        self._parent = None
+        self._ann = None
 
     def __enter__(self):
         frames = _frames()
-        if frames:
-            self.trace_id, self.parent_id = frames[-1]
-        else:
-            self.trace_id = new_trace_id()
-            self.parent_id = None
-        self.span_id = os.urandom(8).hex()
-        frames.append((self.trace_id, self.span_id))
+        parent = frames[-1] if frames else None
+        self._parent = parent
+        if _enabled:  # causal ids only for the Chrome export
+            if parent is not None and parent.span_id:
+                self.trace_id, self.parent_id = parent.trace_id, parent.span_id
+            else:
+                self.trace_id = new_trace_id()
+            self.span_id = os.urandom(8).hex()
+        frames.append(self)
+        ann = _annotation()
+        if ann is not None:
+            self._ann = ann(self.name)
+            self._ann.__enter__()
         self._start = time.perf_counter_ns()
-        if _use_jax_annotations:
-            try:
-                import jax
-
-                self._jax_ctx = jax.profiler.TraceAnnotation(self.name)
-                self._jax_ctx.__enter__()
-            except Exception:
-                logger.debug("jax trace annotation unavailable", exc_info=True)
-                self._jax_ctx = None
         return self
 
     def __exit__(self, *exc) -> None:
         end = time.perf_counter_ns()
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(*exc)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         frames = _frames()
-        if frames and frames[-1][1] == self.span_id:
+        if frames and frames[-1] is self:
             frames.pop()
+        dur = end - self._start
+        self.seconds = dur / 1e9
+        parent = self._parent
+        if parent.__class__ is _Span:
+            parent._child_ns += dur
+        if _metrics.enabled():
+            _C_SPAN_SELF.labels(span=self.name).inc(
+                max(0, dur - self._child_ns) // 1000
+            )
+            hist = _SPAN_HISTOGRAMS.get(self.name)
+            if hist is not None:
+                if hist.labelnames:
+                    hist = hist.labels(
+                        **{k: self.args.get(k, "") for k in hist.labelnames}
+                    )
+                hist.observe(self.seconds)
+        if not self.span_id:
+            return
         args = dict(self.args) if self.args else {}
         args["trace_id"] = self.trace_id
         args["span_id"] = self.span_id
@@ -225,7 +326,7 @@ class _Span:
             "name": self.name,
             "ph": "X",  # complete event
             "ts": _wall_us(self._start),  # µs, wall-anchored
-            "dur": (end - self._start) / 1e3,
+            "dur": dur / 1e3,
             "pid": os.getpid(),
             "tid": threading.get_ident() & 0xFFFFFFFF,
             "args": args,
@@ -237,9 +338,9 @@ class _Span:
 
 
 def span(name: str, **args: Any):
-    """``with trace.span("read.prefetch", bytes=n): ...`` — no-op unless
-    tracing is enabled."""
-    if not _enabled:
+    """``with trace.span("read.prefetch", bytes=n): ...`` — a no-op unless
+    the metrics registry (self time) or Chrome tracing (events) is on."""
+    if not (_enabled or _metrics.enabled()):
         return _NOOP
     return _Span(name, args)
 
@@ -418,9 +519,9 @@ def flight_record(name: str, phase: str = "i", **fields: Any) -> None:
     }
     args: Dict[str, Any] = dict(fields) if fields else {}
     frames = getattr(_tls, "frames", None)
-    if frames:
-        args.setdefault("trace_id", frames[-1][0])
-        args.setdefault("parent_id", frames[-1][1])
+    if frames and frames[-1].span_id:
+        args.setdefault("trace_id", frames[-1].trace_id)
+        args.setdefault("parent_id", frames[-1].span_id)
     if args:
         rec["args"] = args
     _flight.append(rec)

@@ -31,16 +31,11 @@ from typing import Iterable, List, Tuple
 
 import numpy as np
 
-from s3shuffle_tpu.metrics import registry as _metrics
+from s3shuffle_tpu.utils import trace
 from s3shuffle_tpu.write.map_output_writer import MapOutputCommitMessage
 from s3shuffle_tpu.write.spill_writer import MapWriterBase
 
 logger = logging.getLogger("s3shuffle_tpu.write")
-
-_H_SERIALIZE = _metrics.REGISTRY.histogram(
-    "write_serialize_seconds",
-    "Per-partition serializer→codec emission latency (serialized-sort path)",
-)
 
 
 class SerializedSortMapWriter(MapWriterBase):
@@ -56,9 +51,9 @@ class SerializedSortMapWriter(MapWriterBase):
         self._spill_offsets: List[np.ndarray] = []
 
     # ------------------------------------------------------------------
-    def write(self, records: Iterable[Tuple]) -> None:
+    def _write(self, records: Iterable[Tuple]) -> None:
         from s3shuffle_tpu.batch import iter_record_batches
-        from s3shuffle_tpu.serializer import observe_partition_pass
+        from s3shuffle_tpu.serializer import count_plane_rows
 
         partitioner = self.dep.partitioner
         for batch in iter_record_batches(
@@ -66,9 +61,9 @@ class SerializedSortMapWriter(MapWriterBase):
         ):
             if batch.n == 0:
                 continue
-            t0 = time.perf_counter_ns() if _metrics.enabled() else 0
-            pids = partitioner.partition_batch(batch)
-            observe_partition_pass(t0, batch.n)
+            with trace.span("record.partition"):
+                pids = partitioner.partition_batch(batch)
+            count_plane_rows("write", batch.n)
             self._batches.append(batch)
             self._pids.append(np.asarray(pids))
             self._buffered += batch.nbytes + pids.nbytes
@@ -81,7 +76,6 @@ class SerializedSortMapWriter(MapWriterBase):
         """One argsort over everything buffered → (grouped batch, partition
         bounds). Clears the buffer."""
         from s3shuffle_tpu.batch import RecordBatch, split_by_partition
-        from s3shuffle_tpu.serializer import observe_partition_pass
 
         big = RecordBatch.concat(self._batches)
         pids = (
@@ -90,12 +84,10 @@ class SerializedSortMapWriter(MapWriterBase):
         self._batches = []
         self._pids = []
         self._buffered = 0
-        t0 = time.perf_counter_ns() if _metrics.enabled() else 0
-        out = split_by_partition(big, pids, self.dep.num_partitions)
-        # rows=0: these rows were already counted at their write() pass —
-        # this is the spill/commit-time re-grouping of the same buffer
-        observe_partition_pass(t0, 0)
-        return out
+        # no rows counted: they were counted at their write() pass — this is
+        # the spill/commit-time re-grouping of the same buffer
+        with trace.span("record.partition"):
+            return split_by_partition(big, pids, self.dep.num_partitions)
 
     def _emit_partition(self, sink, rows) -> None:
         """Serialize one partition's rows through serializer→codec into
@@ -103,20 +95,18 @@ class SerializedSortMapWriter(MapWriterBase):
         are self-delimiting, so consecutive emissions concatenate."""
         from s3shuffle_tpu.codec.framing import CodecOutputStream
 
-        t0 = time.perf_counter_ns() if _metrics.enabled() else 0
-        if self.codec is not None:
-            codec_stream = CodecOutputStream(self.codec, sink, close_sink=False)
-            target = codec_stream
-        else:
-            codec_stream = None
-            target = sink
-        w = self.serializer.new_write_stream(target)
-        w.write_batch(rows)
-        w.close()
-        if codec_stream is not None:
-            codec_stream.close()
-        if t0:
-            _H_SERIALIZE.observe((time.perf_counter_ns() - t0) / 1e9)
+        with trace.span("record.serialize"):
+            if self.codec is not None:
+                codec_stream = CodecOutputStream(self.codec, sink, close_sink=False)
+                target = codec_stream
+            else:
+                codec_stream = None
+                target = sink
+            w = self.serializer.new_write_stream(target)
+            w.write_batch(rows)
+            w.close()
+            if codec_stream is not None:
+                codec_stream.close()
 
     def _spill(self) -> None:
         if not self._batches:

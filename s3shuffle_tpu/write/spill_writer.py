@@ -30,6 +30,7 @@ import numpy as np
 
 from s3shuffle_tpu.codec.framing import FrameCodec
 from s3shuffle_tpu.metrics import registry as _metrics
+from s3shuffle_tpu.utils import trace
 from s3shuffle_tpu.write.map_output_writer import MapOutputCommitMessage, MapOutputWriter
 
 logger = logging.getLogger("s3shuffle_tpu.write")
@@ -39,10 +40,6 @@ _H_SPILL = _metrics.REGISTRY.histogram(
 )
 _C_SPILL_BYTES = _metrics.REGISTRY.counter(
     "write_spill_bytes_total", "Bytes moved to local spill files"
-)
-_H_COMMIT = _metrics.REGISTRY.histogram(
-    "write_commit_seconds",
-    "Map-output commit latency (drain + serialize + upload + index)",
 )
 
 
@@ -174,8 +171,15 @@ class MapWriterBase:
         self._records_written = 0
         self._stopped = False
         self.spill_count = 0
+        self._task_ctx = None  # write()'s trace context, adopted by stop()
 
     def write(self, records) -> None:
+        """One map task's records (Spark's ``write``), under ``task.map``."""
+        with trace.span("task.map"):
+            self._task_ctx = trace.current_context()
+            self._write(records)
+
+    def _write(self, records) -> None:
         raise NotImplementedError
 
     def _commit(self) -> MapOutputCommitMessage:
@@ -186,25 +190,26 @@ class MapWriterBase:
 
     # ------------------------------------------------------------------
     def stop(self, success: bool) -> Optional[MapOutputCommitMessage]:
+        """Commit (or abort) the map output: the second half of the
+        ``task.map`` span that :meth:`write` opened."""
         if self._stopped:
             return None
         self._stopped = True
+        with trace.context(self._task_ctx), trace.span("task.map"):
+            return self._stop(success)
+
+    def _stop(self, success: bool) -> Optional[MapOutputCommitMessage]:
         if not success:
             self._on_abort()
             self.output_writer.abort()
             self._cleanup_spill()
             return None
-        from s3shuffle_tpu.utils import trace
-
         try:
-            t0 = time.perf_counter_ns()
             with trace.span(
                 "write.commit", map_id=self.map_id, records=self._records_written
-            ):
+            ) as commit:
                 message = self._commit()
             if _metrics.enabled():
-                seconds = (time.perf_counter_ns() - t0) / 1e9
-                _H_COMMIT.observe(seconds)
                 from s3shuffle_tpu.metrics.stats import COLLECTOR
 
                 # map-commit ShuffleStats entry (reduce side reports at drain)
@@ -213,7 +218,7 @@ class MapWriterBase:
                     map_id=self.map_id,
                     bytes=int(np.sum(message.partition_lengths)),
                     records=self._records_written,
-                    seconds=seconds,
+                    seconds=commit.seconds,
                     spills=self.spill_count,
                 )
             return message
@@ -335,7 +340,7 @@ class ShuffleMapWriter(MapWriterBase):
         self._sidecar_threshold = 0
 
     # ------------------------------------------------------------------
-    def write(self, records: Iterable[Tuple[Any, Any]]) -> None:
+    def _write(self, records: Iterable[Tuple[Any, Any]]) -> None:
         from s3shuffle_tpu.batch import RecordBatch
 
         dep = self.dep
@@ -422,23 +427,24 @@ class ShuffleMapWriter(MapWriterBase):
 
     def _write_batches(self, batches) -> None:
         from s3shuffle_tpu.batch import split_by_partition
-        from s3shuffle_tpu.serializer import observe_partition_pass
+        from s3shuffle_tpu.serializer import count_plane_rows
 
         dep = self.dep
         for batch in batches:
             if batch.n == 0:
                 continue
-            t0 = time.perf_counter_ns() if _metrics.enabled() else 0
-            pids = dep.partitioner.partition_batch(batch)
-            grouped, bounds = split_by_partition(batch, pids, dep.num_partitions)
-            observe_partition_pass(t0, batch.n)
-            for pid in range(dep.num_partitions):
-                lo, hi = int(bounds[pid]), int(bounds[pid + 1])
-                if hi > lo:
-                    sl = grouped.slice_rows(lo, hi)
-                    if self._combine_gate:
-                        sl = self._maybe_combine_chunk(pid, sl)
-                    self._pipelines[pid].record_writer.write_batch(sl)
+            with trace.span("record.partition"):
+                pids = dep.partitioner.partition_batch(batch)
+                grouped, bounds = split_by_partition(batch, pids, dep.num_partitions)
+            count_plane_rows("write", batch.n)
+            with trace.span("record.serialize"):
+                for pid in range(dep.num_partitions):
+                    lo, hi = int(bounds[pid]), int(bounds[pid + 1])
+                    if hi > lo:
+                        sl = grouped.slice_rows(lo, hi)
+                        if self._combine_gate:
+                            sl = self._maybe_combine_chunk(pid, sl)
+                        self._pipelines[pid].record_writer.write_batch(sl)
             self._records_written += batch.n
             if self._buffered_total() > self.spill_memory_budget:
                 self._spill()
@@ -526,7 +532,8 @@ class ShuffleMapWriter(MapWriterBase):
             # finish() BEFORE the writer exists: the codec stream's final
             # frames land in the local sink and complete the fused checksum,
             # which then replaces the writer's byte-serial hashing outright
-            fused_value = pipeline.finish()
+            with trace.span("record.serialize"):
+                fused_value = pipeline.finish()
             writer = self.output_writer.get_partition_writer(
                 pid, precomputed_checksum=fused_value
             )

@@ -446,7 +446,7 @@ def test_worker_metrics_colliding_counter_names_dedup(tmp_path):
         cfg = ShuffleConfig(root_dir=f"file://{tmp_path}", app_id="metrics2")
         agent = WorkerAgent(svc.address, config=cfg, worker_id="w-dedup")
         metrics = MetricsServer(agent, host="127.0.0.1", port=0)
-        trace.enable(str(tmp_path / "trace.json"), jax_annotations=False)
+        trace.enable(str(tmp_path / "trace.json"))
         try:
             trace.count("dedup.check", 3)
             trace.count("dedup/check", 4)

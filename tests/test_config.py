@@ -82,7 +82,7 @@ def test_trace_records_spans_and_counters(tmp_path):
     from s3shuffle_tpu.utils import trace
 
     trace.reset()
-    trace.enable(str(tmp_path / "trace.json"), jax_annotations=False)
+    trace.enable(str(tmp_path / "trace.json"))
     try:
         from s3shuffle_tpu.config import ShuffleConfig
         from s3shuffle_tpu.shuffle import ShuffleContext

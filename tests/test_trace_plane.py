@@ -31,7 +31,7 @@ def trace_sandbox(tmp_path):
     an unrelated test)."""
     trace.reset()
     path = str(tmp_path / "trace.json")
-    trace.enable(path, jax_annotations=False)
+    trace.enable(path)
     yield path
     trace.disable()
     trace.reset()
@@ -560,7 +560,7 @@ def test_distributed_job_produces_one_merged_linked_trace(tmp_path, monkeypatch)
     Dispatcher.reset()
     trace.reset()
     trace_file = str(tmp_path / "merged_trace.json")
-    trace.enable(trace_file, jax_annotations=False)
+    trace.enable(trace_file)
     # children inherit the env at spawn: worker-side tracing ships span
     # shards to the coordinator; any worker-local residue flushes into tmp
     monkeypatch.setenv("S3SHUFFLE_TRACE", str(tmp_path / "worker_residue.json"))
@@ -679,7 +679,7 @@ def _recorded_roundtrip(tmp_path, tag, trace_on):
     Dispatcher.reset()
     trace.reset()
     if trace_on:
-        trace.enable(str(tmp_path / f"{tag}.json"), jax_annotations=False)
+        trace.enable(str(tmp_path / f"{tag}.json"))
     else:
         trace.disable()
     try:

@@ -277,6 +277,25 @@ def _write_plane_line(snapshot: dict) -> Optional[str]:
     return "Write plane: " + "; ".join(parts)
 
 
+def _launch_queue_clause(snapshot: dict, op: str) -> str:
+    """Device launches of one codec ``op`` and how many launches were queued
+    ahead of each on its device when it was dispatched; "" when ``op``
+    launched nothing."""
+
+    def total(name: str) -> float:
+        return sum(
+            float(s.get("value", 0))
+            for s in snapshot.get(name, {}).get("series", [])
+            if s.get("labels", {}).get("op") == op
+        )
+
+    launches = total("codec_launches_total")
+    if launches <= 0:
+        return ""
+    ahead = total("codec_launches_ahead_total")
+    return f"; {launches:g} device launches, {ahead / launches:.2f} queued ahead of each"
+
+
 def _codec_line(snapshot: dict) -> Optional[str]:
     """One-line codec digest: batch encode throughput (raw MB/s through the
     compress+frame calls), host assembly throughput, fused-CRC coverage
@@ -307,7 +326,7 @@ def _codec_line(snapshot: dict) -> Optional[str]:
     )
     if inflight > 0:
         line += f"; {inflight:g} encode batches in flight"
-    return line
+    return line + _launch_queue_clause(snapshot, "encode")
 
 
 def _codec_read_line(snapshot: dict) -> Optional[str]:
@@ -333,7 +352,7 @@ def _codec_read_line(snapshot: dict) -> Optional[str]:
     )
     if inflight > 0:
         line += f"; {inflight:g} decode batches in flight"
-    return line
+    return line + _launch_queue_clause(snapshot, "decode")
 
 
 def _coding_plane_line(snapshot: dict) -> Optional[str]:
