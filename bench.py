@@ -779,10 +779,13 @@ def _device_kernel_rates_impl():
         # the whole point of the fusion (BASELINE "fused CRC32C" goal)
         from s3shuffle_tpu.ops.checksum import raw_crc_graph_fn
 
+        # the tables are placed outside the traced loop: placed inside it,
+        # the cached device table would hold the trace's tracer
         crc_fn, crc_tables = raw_crc_graph_fn(POLY_CRC32C, L, 2 * B)
+        enc_tables = crc_tables(None)
         delta_rate(
             lambda d: tlz._encode_fused_math(
-                d, n_groups, lambda x: crc_fn(x, *crc_tables(None))
+                d, n_groups, lambda x: crc_fn(x, *enc_tables)
             )[6:11],
             "tpu_tlz_encode_fused_mb_s",
         )
@@ -863,7 +866,8 @@ def _device_kernel_rates_impl():
         # dispatch (the validation certificate's device half; ops/tlz.py
         # _decode_fused_math). Lands in bench_tpu_last_good.json via the
         # per-metric merge like every other kernel rate.
-        crc_fn_dec, dec_tables = raw_crc_graph_fn(POLY_CRC32C, L, B)
+        crc_fn_dec, dec_tables_of = raw_crc_graph_fn(POLY_CRC32C, L, B)
+        dec_tables = dec_tables_of(None)
         n_lits_arr = (n_groups - n_match.astype(np.int64)
                       - n_split.astype(np.int64)).astype(np.int32)
         dnl = jax.device_put(n_lits_arr)
@@ -876,7 +880,7 @@ def _device_kernel_rates_impl():
                         (lambda dr: (dr[0][:, ::997], dr[1]))(
                             tlz._decode_fused_math(
                                 m, c, sp, o, k, carry, nl, n_groups,
-                                lambda x: crc_fn_dec(x, *dec_tables(None)),
+                                lambda x: crc_fn_dec(x, *dec_tables),
                             )
                         ),
                     ),

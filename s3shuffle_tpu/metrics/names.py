@@ -140,6 +140,7 @@ KNOWN_METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "codec_launches_total": ("counter", ("op",)),
     "codec_launch_rows_total": ("counter", ("op", "row")),
     "codec_launches_ahead_total": ("counter", ("op",)),
+    "codec_decode_jump_rounds_total": ("counter", ()),
     # --- trace plane: span shards, flight recorder, fleet telemetry, cost
     # (utils/trace.py, metadata/service.py, s3shuffle_tpu/costs.py) ---
     "trace_shard_bytes_total": ("counter", ()),

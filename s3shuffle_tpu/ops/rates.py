@@ -94,6 +94,11 @@ _C_LAUNCHES_AHEAD = _metrics.REGISTRY.counter(
     "drained on the same device when each was dispatched, by op",
     labelnames=("op",),
 )
+_C_JUMP_ROUNDS = _metrics.REGISTRY.counter(
+    "codec_decode_jump_rounds_total",
+    "Pointer-jump rounds run by device decode launches: each launch runs "
+    "as many as its deepest source chain needs, at most log2(block)",
+)
 
 #: launches dispatched and not yet drained, per device, process-wide
 _queue_lock = threading.Lock()
@@ -117,6 +122,12 @@ def launch_drained(device) -> None:
     """A launch's outputs reached the host (or it was abandoned)."""
     with _queue_lock:
         _queued[device] -= 1
+
+
+def decode_jump_rounds(rounds: int) -> None:
+    """A drained decode launch ran ``rounds`` pointer-jump rounds."""
+    if _metrics.enabled():
+        _C_JUMP_ROUNDS.inc(rounds)
 
 
 #: cache filename shared with bench.py (kept in sync by convention; bench

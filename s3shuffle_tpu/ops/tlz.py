@@ -33,10 +33,11 @@ the hardware up instead of translating LZ4:
   a run), builds a per-byte source map (literal bytes are fixed points;
   match bytes point at ``pos - distance``; split-group bytes at
   ``pos - d_left`` before k and ``pos - d_right`` after) and resolves
-  chains with **pointer jumping**: log2(block) doubling rounds of one
-  parallel gather each, then a final gather from the literal plane. No
-  sequential back-reference chasing — equally fast on TPU and in
-  vectorized numpy on the host.
+  chains with **pointer jumping**: doubling rounds of one parallel gather
+  each until converged, at most log2(block); the device carries each
+  position's byte in the same gather, the host gathers once more from the
+  literal plane. No sequential back-reference chasing — equally fast on
+  TPU and in vectorized numpy on the host.
 
 Wire format of one TLZ frame payload (fits the shared 9-byte frame header,
 codec_id = ``tpu-lz``):
@@ -1075,7 +1076,8 @@ def decode_payload_numpy(
     # at offset + lane. Pointer jumping (src = src[src] — the DOUBLING update;
     # following a fixed map would advance one hop per round and never resolve
     # long periodic chains) reaches literal bytes in log2 rounds; the host
-    # loop exits early once converged — typical data needs 2-5 rounds.
+    # loop exits early once converged — terasort's column-framed values
+    # need 9 doubling rounds at 256 KiB blocks.
     out = sparse
     match_groups = np.flatnonzero(is_match)
     if len(match_groups) or len(split_idx):
@@ -1116,7 +1118,20 @@ def _decode_math(
 ):
     """The raw (unjitted) decode computation — shared by the standalone
     jitted kernel and larger fused traces (e.g. the multichip dryrun's
-    in-graph encode→decode roundtrip check).
+    in-graph encode→decode roundtrip check): the decoded (B, n_bytes)
+    uint8 rows of :func:`_decode_counted_math`."""
+    return _decode_counted_math(
+        is_match, is_cont, is_split, offs_padded, ks_padded, lits_padded,
+        n_groups,
+    )[0]
+
+
+def _decode_counted_math(
+    is_match, is_cont, is_split, offs_padded, ks_padded, lits_padded,
+    n_groups: int,
+):
+    """Decode, returning ``(decoded, rounds)``: the (B, n_bytes) uint8 rows
+    and the int32 number of pointer-jump rounds the launch ran.
 
     is_match/is_cont/is_split: (B, G) bool; offs_padded: (B, G) int32
     (stored match DISTANCES in order); ks_padded: (B, G) int32 (stored
@@ -1127,7 +1142,7 @@ def _decode_math(
     prefix at the left neighbor's distance and suffix at the right
     neighbor's.
     """
-    _jax_mod, jnp = _jax()
+    jax, jnp = _jax()
     n_bytes = n_groups * GROUP
     b = is_match.shape[0]
     idx = jnp.arange(n_groups, dtype=jnp.int32)
@@ -1164,9 +1179,29 @@ def _decode_math(
     # checksum layer, unlike an out-of-bounds gather
     src = jnp.where(match_b, jnp.clip(off_b, 0, n_bytes - 1), pos[None, :])
     src = jnp.where(split_b, jnp.clip(split_src, 0, n_bytes - 1), src)
-    for _ in range(_jump_rounds(n_bytes)):
-        src = jnp.take_along_axis(src, src, axis=1)
-    return jnp.take_along_axis(sparse, src, axis=1)
+    # Pointer jumping over packed words ``pointer << 8 | byte``: one gather
+    # per round doubles every pointer's reach (src = src[src]) and carries
+    # the byte its old pointer held, so after round k a word holds the byte
+    # 2**k - 1 hops back. Valid sources point strictly backward, so every
+    # chain ends at a literal within n_bytes - 1 <= 2**cap - 1 hops; the
+    # loop stops at the first round that moves no pointer of the launch
+    # (the chains were resolved a round earlier, so the bytes now are
+    # too), else at the cap.
+    if n_bytes > 1 << 23:
+        raise ValueError(f"{n_bytes}-byte blocks overflow the packed pointers")
+    cap = _jump_rounds(n_bytes)
+
+    def jump(state):
+        words, _moved, rounds = state
+        nxt = jnp.take_along_axis(words, words >> 8, axis=1)
+        return nxt, jnp.any((nxt >> 8) != (words >> 8)), rounds + 1
+
+    words, _moved, rounds = jax.lax.while_loop(
+        lambda state: state[1] & (state[2] < cap),
+        jump,
+        ((src << 8) | sparse.astype(jnp.int32), jnp.array(True), jnp.int32(0)),
+    )
+    return (words & 0xFF).astype(jnp.uint8), rounds
 
 
 class _NativeEncodeScratch(threading.local):
@@ -1356,9 +1391,10 @@ def _decode_fused_math(
     plane certifies each frame's STORED bytes without a separate host hashing
     pass over the payload bulk (the read-side mirror of
     :func:`_encode_fused_math`). ``n_lits``: (B,) int32 literal-group counts
-    (the staged literal plane is front-aligned in literal order)."""
+    (the staged literal plane is front-aligned in literal order). Returns
+    ``(decoded, crcs, rounds)``, ``rounds`` as :func:`_decode_counted_math`."""
     _jax_mod, jnp = _jax()
-    decoded = _decode_math(
+    decoded, rounds = _decode_counted_math(
         is_match, is_cont, is_split, offs_padded, ks_padded, lits_padded,
         n_groups,
     )
@@ -1372,13 +1408,13 @@ def _decode_fused_math(
     lits_flat = lits_padded.reshape(b, n_bytes)
     gathered = jnp.take_along_axis(lits_flat, jnp.maximum(src, 0), axis=1)
     lits_right = jnp.where(src >= 0, gathered, 0).astype(jnp.uint8)
-    return decoded, crc_fn(lits_right)
+    return decoded, crc_fn(lits_right), rounds
 
 
 @functools.lru_cache(maxsize=8)
 def _decode_kernel(n_groups: int):
-    """Batched device decoder: fixed-shape inputs (padded); log2 rounds of
-    pointer-jumping gathers, then one gather from the literal plane. Kept as
+    """Batched device decoder: fixed-shape inputs (padded); pointer-jumping
+    gathers until the launch's chains resolve. Kept as
     the variable-batch entry for fused traces and the bench; the read plane
     routes through :func:`_decode_batch_kernel` (fixed batch rows, donated
     staging — no retrace per distinct batch size)."""
@@ -1401,13 +1437,14 @@ def _decode_batch_kernel(batch_rows: int, n_groups: int, poly: Optional[int]):
     path jitted over whatever batch size arrived, so XLA recompiled per
     distinct frame-run length (every tail run of every partition). Staged
     plane arrays are DONATED so XLA may reuse their device buffers. ``poly``
-    selects the fused CRC variant (None = decode only)."""
+    selects the fused CRC variant (None = decode only). Either variant's
+    last output is the pointer-jump rounds the launch ran."""
     jax, _jnp = _jax()
     from s3shuffle_tpu.ops import rates
 
     if poly is None:
         return rates.timed_first_call("tlz_decode", jax.jit(
-            functools.partial(_decode_math, n_groups=n_groups),
+            functools.partial(_decode_counted_math, n_groups=n_groups),
             donate_argnums=(0, 1, 2, 3, 4, 5),
         ))
     from s3shuffle_tpu.ops.checksum import raw_crc_graph_fn
@@ -1646,14 +1683,12 @@ def decode_batch_device(
         t0 = _time.perf_counter()
         try:
             with trace.span("codec.decode.device"):
-                if poly is None:
-                    decoded = np.asarray(outs)
-                    raw_crcs = None
-                else:
-                    decoded = np.asarray(outs[0])
-                    raw_crcs = np.asarray(outs[1])
+                decoded = np.asarray(outs[0])
+                raw_crcs = None if poly is None else np.asarray(outs[1])
+                rounds = int(outs[-1])
         finally:
             rates.launch_drained(dev)
+        rates.decode_jump_rounds(rounds)
         if disp is not None:
             disp.release(slot)
             if backpressure:

@@ -277,22 +277,22 @@ def _write_plane_line(snapshot: dict) -> Optional[str]:
     return "Write plane: " + "; ".join(parts)
 
 
+def _op_total(snapshot: dict, name: str, op: str) -> float:
+    return sum(
+        float(s.get("value", 0))
+        for s in snapshot.get(name, {}).get("series", [])
+        if s.get("labels", {}).get("op") == op
+    )
+
+
 def _launch_queue_clause(snapshot: dict, op: str) -> str:
     """Device launches of one codec ``op`` and how many launches were queued
     ahead of each on its device when it was dispatched; "" when ``op``
     launched nothing."""
-
-    def total(name: str) -> float:
-        return sum(
-            float(s.get("value", 0))
-            for s in snapshot.get(name, {}).get("series", [])
-            if s.get("labels", {}).get("op") == op
-        )
-
-    launches = total("codec_launches_total")
+    launches = _op_total(snapshot, "codec_launches_total", op)
     if launches <= 0:
         return ""
-    ahead = total("codec_launches_ahead_total")
+    ahead = _op_total(snapshot, "codec_launches_ahead_total", op)
     return f"; {launches:g} device launches, {ahead / launches:.2f} queued ahead of each"
 
 
@@ -333,8 +333,9 @@ def _codec_read_line(snapshot: dict) -> Optional[str]:
     """One-line READ-side codec digest: batch decode throughput (decoded
     MB/s through the batch decompress calls), fused-validation coverage
     (frames whose stored-byte CRC certificate rode the decode launch — each
-    one a skipped host hashing pass), and live in-flight decode window
-    occupancy."""
+    one a skipped host hashing pass), live in-flight decode window
+    occupancy, the decode launch queue, and the pointer-jump rounds each
+    decode launch ran."""
     dec_bytes = _counter_total(snapshot, "codec_decode_bytes_total")
     series = snapshot.get("codec_decode_batch_seconds", {}).get("series", [])
     dec_seconds = sum(float(s.get("sum", 0.0)) for s in series)
@@ -352,7 +353,12 @@ def _codec_read_line(snapshot: dict) -> Optional[str]:
     )
     if inflight > 0:
         line += f"; {inflight:g} decode batches in flight"
-    return line + _launch_queue_clause(snapshot, "decode")
+    line += _launch_queue_clause(snapshot, "decode")
+    launches = _op_total(snapshot, "codec_launches_total", "decode")
+    rounds = _counter_total(snapshot, "codec_decode_jump_rounds_total")
+    if launches > 0 and rounds > 0:
+        line += f"; {rounds / launches:.2f} pointer-jump rounds per launch"
+    return line
 
 
 def _coding_plane_line(snapshot: dict) -> Optional[str]:
@@ -842,6 +848,10 @@ def _selftest() -> int:
     buckets[4] = 90
     buckets[8] = 10
     metrics = _synthetic_snapshot()
+    # a decode launch series, so the read line's per-launch clauses render
+    metrics["codec_launches_total"]["series"].append(
+        {"labels": {"op": "decode"}, "value": 2}
+    )
     report = {
         "shuffle_id": 7,
         "map_tasks": 4,
@@ -925,11 +935,13 @@ def _selftest() -> int:
         assert needle in text, f"codec line missing {needle!r}:\n{text}"
     # the READ-side codec digest renders from the synthetic decode series
     # (1 MiB decoded over a 3.08s histogram; 7 fused-validated frames;
-    # gauge 7 decode batches in flight)
+    # gauge 7 decode batches in flight; 7 jump rounds over 2 decode launches)
     for needle in (
         "Codec read: decode 0.3 MB/s over 100 batches",
         "fused-validated 7 frames",
         "7 decode batches in flight",
+        "2 device launches",
+        "3.50 pointer-jump rounds per launch",
     ):
         assert needle in text, f"codec read line missing {needle!r}:\n{text}"
     # the mesh-plane digest renders from the synthetic mesh_* series (two
