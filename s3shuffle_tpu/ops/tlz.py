@@ -26,7 +26,9 @@ the hardware up instead of translating LZ4:
   verified by exact compare, so hash collisions cost missed matches, never
   wrong output. A vectorized continuation-promotion pass then retries each
   group at the previous group's distance, aligning chains so the cont
-  bitmap can elide them;
+  bitmap can elide them. The device compares every window (verification,
+  promotion, the split tier's byte runs) as a pair of packed 32-bit words,
+  so each test gathers two elements per group, not eight bytes;
 - sources may overlap their destination (distance < 8), so runs of ANY
   period — classic LZ77 RLE — fall out free;
 - decoding reconstructs elided distances with a rank gather (constant along
@@ -217,19 +219,47 @@ def _candidate_math(blocks_u8, n_groups: int):
 def _plane_decisions_math(blocks_u8, cand_d, n_groups: int):
     """Match/continuation/split decisions from the candidate positions — the
     gather-heavy middle of the encoder. Returns FULL (uncompacted) planes:
-    (is_match, is_cont, is_split, dists, ks)."""
+    (is_match, is_cont, is_split, dists, ks).
+
+    Every compare reads packed words, not bytes: ``words[:, GROUP + p]``
+    holds bytes p..p+3 of the row (little-endian), so the 8-byte window at
+    p is the pair at p and p + 4. Each window test gathers one pair per
+    group — two elements where a per-byte gather fetched eight."""
     jax, jnp = _jax()
     b = blocks_u8.shape[0]
     n_bytes = n_groups * GROUP
-    buf = blocks_u8.astype(jnp.int32)  # (B, n_bytes)
-    lanes = jnp.arange(GROUP, dtype=jnp.int32)
-    groups = buf.reshape(b, n_groups, GROUP)
+    half = GROUP // 2
+    # GROUP zero bytes in front: a suffix window may start up to GROUP bytes
+    # before byte 0 (its bytes there are masked below), and no window starts
+    # after byte n_bytes - GROUP, so no index needs a clip
+    pad = GROUP
+    padded = jnp.pad(blocks_u8.astype(jnp.uint32), ((0, 0), (pad, 0)))
+    n_words = pad + n_bytes - half + 1
+    words = padded[:, :n_words]
+    for k in range(1, half):
+        words = words | (padded[:, k : k + n_words] << (8 * k))
+    # each group's own pair: a strided slice, no gather
+    own_lo, own_hi = (
+        jax.lax.slice(words, (0, pad + o), (b, n_words), (1, GROUP))
+        for o in (0, half)
+    )
     dest = jnp.arange(n_groups, dtype=jnp.int32) * GROUP
 
-    def window_at(pos):
-        # gather the GROUP-byte window starting at each position in ``pos``
-        idx = (pos[:, :, None] + lanes).reshape(b, -1)
-        return jnp.take_along_axis(buf, idx, axis=1).reshape(b, -1, GROUP)
+    def diff_at(start):
+        # XOR of the window at each (B, G) start with the group's own: byte
+        # k of the pair is zero iff window byte k equals group byte k
+        idx = start + pad
+        lo = jnp.take_along_axis(words, idx, axis=1)
+        hi = jnp.take_along_axis(words, idx + half, axis=1)
+        return lo ^ own_lo, hi ^ own_hi
+
+    def same_at(start):
+        lo, hi = diff_at(start)
+        return (lo == 0) & (hi == 0)
+
+    def byte_eq(diff, k):
+        word = diff[k // half]  # unsigned: the shift brings in no sign bits
+        return ((word >> (8 * (k % half))) & 0xFF) == 0
 
     # verify exact equality (hash collisions ⇒ missed match, never wrong);
     # matches are stored as DISTANCES (dest - src, 1..MAX_DIST) — constant
@@ -237,11 +267,7 @@ def _plane_decisions_math(blocks_u8, cand_d, n_groups: int):
     # which decouples block size from the u16 wire width
     safe = jnp.maximum(cand_d, 0)
     cand_dist = dest[None, :] - cand_d
-    is_match = (
-        jnp.all(window_at(safe) == groups, axis=2)
-        & (cand_d >= 0)
-        & (cand_dist <= MAX_DIST)
-    )
+    is_match = same_at(safe) & (cand_d >= 0) & (cand_dist <= MAX_DIST)
     dists = jnp.where(is_match, cand_dist, 0)
 
     # continuation promotion: retry each group at the previous group's
@@ -258,12 +284,7 @@ def _plane_decisions_math(blocks_u8, cand_d, n_groups: int):
             [jnp.zeros((b, 1), bool), is_match[:, :-1]], axis=1
         )
         # source = dest - prev_dist >= 0 holds: prev_dist <= 8(g-1) < 8g
-        c_src = dest[None, :] - prev_dist
-        c_ok = (
-            prev_match
-            & (prev_dist > 0)
-            & jnp.all(window_at(jnp.maximum(c_src, 0)) == groups, axis=2)
-        )
+        c_ok = prev_match & (prev_dist > 0) & same_at(dest[None, :] - prev_dist)
         dists = jnp.where(c_ok, prev_dist, dists)
         is_match = is_match | c_ok
 
@@ -285,20 +306,21 @@ def _plane_decisions_math(blocks_u8, cand_d, n_groups: int):
     next_match = jnp.concatenate(
         [is_match[:, 1:], jnp.zeros((b, 1), bool)], axis=1
     )
-    byte_pos = dest[None, :, None] + lanes[None, None, :]  # (1, G, GROUP)
-    pre_src = byte_pos - prev_dist[:, :, None]  # ≥ 8 when prev is a match
-    suf_src = byte_pos - next_dist[:, :, None]  # may be < 0 near the front
-    gather = lambda idx: jnp.take_along_axis(  # noqa: E731
-        buf, jnp.clip(idx, 0, n_bytes - 1).reshape(b, -1), axis=1
-    ).reshape(b, n_groups, GROUP)
-    pre_eq = gather(pre_src) == groups
-    suf_eq = (gather(suf_src) == groups) & (suf_src >= 0)
-    # longest all-true prefix of pre_eq; first index with all-true suffix
-    prefix_run = jnp.sum(jnp.cumprod(pre_eq, axis=2), axis=2)
-    suffix_start = GROUP - jnp.sum(
-        jnp.cumprod(suf_eq[:, :, ::-1], axis=2), axis=2
-    )
-    ks = suffix_start.astype(jnp.int32)
+    pre_diff = diff_at(dest[None, :] - prev_dist)  # starts ≥ 8 when prev matches
+    suf_start = dest[None, :] - next_dist  # may be < 0 near the front
+    suf_diff = diff_at(suf_start)
+    # longest all-equal prefix, and first byte of the longest all-equal
+    # suffix whose sources lie in the block: cumprod/sum, one byte at a time
+    run = jnp.ones((b, n_groups), bool)
+    prefix_run = jnp.zeros((b, n_groups), jnp.int32)
+    for k in range(GROUP):
+        run = run & byte_eq(pre_diff, k)
+        prefix_run = prefix_run + run
+    run = jnp.ones((b, n_groups), bool)
+    ks = jnp.full((b, n_groups), GROUP, jnp.int32)
+    for k in reversed(range(GROUP)):
+        run = run & byte_eq(suf_diff, k) & (suf_start + k >= 0)
+        ks = ks - run
     is_split = (
         ~is_match
         & prev_match
