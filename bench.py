@@ -654,34 +654,6 @@ def device_kernel_rates():
     return out
 
 
-def _run_pallas_probes(out, pallas_probe, pallas_interp, batch, dev,
-                       nbytes, crc_pallas, poly):
-    """The hand-written-kernel probes (ops/crc_pallas.py,
-    coding/gf_pallas.py), each in its own guard so one missing lowering
-    writes ``<metric>_error`` without erasing the rest. TPU-only in the
-    normal bench flow — see the call site."""
-    crc_tables = crc_pallas._device_tables(poly)
-    pallas_probe(
-        "tpu_crc32c_pallas_mb_s",
-        lambda d: crc_pallas.crc_raw_in_graph(d, crc_tables, pallas_interp),
-        (dev,), nbytes,
-    )
-    try:
-        import jax
-
-        from s3shuffle_tpu.coding import gf, gf_pallas
-
-        gf_k = 8
-        gf_g, gf_l = 16, nbytes // (16 * gf_k)
-        gf_chunks = batch.reshape(gf_g, gf_k, gf_l)
-        gf_consts = gf_pallas._bit_constants(gf.parity_coefficients(2, gf_k))
-        gf_call = gf_pallas._encode_call(gf_g, gf_l, gf_consts, pallas_interp)
-        dgf = jax.device_put(gf_chunks)
-        pallas_probe("tpu_gf_encode_mb_s", lambda d: gf_call(d), (dgf,), nbytes)
-    except Exception as e:
-        out["tpu_gf_encode_mb_s_error"] = str(e)[:160]
-
-
 def _device_kernel_rates_impl():
     """Device-kernel rates for the offload building blocks, plus host↔device
     link rates. Each kernel is timed as ``lax.scan`` loops of two lengths
@@ -781,7 +753,7 @@ def _device_kernel_rates_impl():
 
         # the tables are placed outside the traced loop: placed inside it,
         # the cached device table would hold the trace's tracer
-        crc_fn, crc_tables = raw_crc_graph_fn(POLY_CRC32C, L, 2 * B)
+        crc_fn, crc_tables = raw_crc_graph_fn(POLY_CRC32C, L)
         enc_tables = crc_tables(None)
         delta_rate(
             lambda d: tlz._encode_fused_math(
@@ -838,7 +810,9 @@ def _device_kernel_rates_impl():
                 lambda m, c, sp, o, k, l: jax.lax.scan(
                     lambda carry, _: (
                         carry ^ jnp.uint8(1),
-                        tlz._decode_math(m, c, sp, o, k, carry, n_groups)[:, ::997],
+                        tlz._decode_counted_math(
+                            m, c, sp, o, k, carry, n_groups
+                        )[0][:, ::997],
                     ),
                     l,
                     None,
@@ -866,7 +840,7 @@ def _device_kernel_rates_impl():
         # dispatch (the validation certificate's device half; ops/tlz.py
         # _decode_fused_math). Lands in bench_tpu_last_good.json via the
         # per-metric merge like every other kernel rate.
-        crc_fn_dec, dec_tables_of = raw_crc_graph_fn(POLY_CRC32C, L, B)
+        crc_fn_dec, dec_tables_of = raw_crc_graph_fn(POLY_CRC32C, L)
         dec_tables = dec_tables_of(None)
         n_lits_arr = (n_groups - n_match.astype(np.int64)
                       - n_split.astype(np.int64)).astype(np.int32)
@@ -907,77 +881,29 @@ def _device_kernel_rates_impl():
                 f"timing jitter (t{N1}={t1:.3f}s, t{N2}={t2:.3f}s)"
             )
 
-        # --- hand-written Pallas kernels (ops/crc_pallas.py,
-        # coding/gf_pallas.py): cold-compile wall (first jitted call:
-        # trace + lower + Mosaic compile + run) and warm scan-delta rate,
-        # recorded separately. Each metric in its own guard so one missing
-        # lowering writes `<metric>_error` without erasing the rest — the
-        # per-metric cache merge keeps every last-good number. These fields
-        # feed the measured-rate gate (ops/rates.py): a kernel is only
-        # SELECTED in production once its rate here beats the host.
-        def pallas_probe(metric, body, args, nbytes):
-            """``body(carry, *rest)`` with ``args[0]`` the uint8 carry the
-            scan XOR-mutates (so the loop body cannot be hoisted)."""
-            stem = metric[:-5] if metric.endswith("_mb_s") else metric
-            try:
-                jf = jax.jit(body)
-                t0 = time.perf_counter()
-                r = jf(*args)
-                jax.tree_util.tree_map(lambda x: x.block_until_ready(), r)
-                out[f"{stem}_cold_s"] = round(time.perf_counter() - t0, 3)
+        # GF(2^8) parity: the table kernel coding/gf.py runs (m=2, k=8
+        # stripe groups over the same staged bytes), under the metric the
+        # measured-rate gate reads for op "gf_encode"
+        from s3shuffle_tpu.coding import gf
 
-                def loop(length):
-                    looped = jax.jit(
-                        lambda *a: jax.lax.scan(
-                            lambda carry, _: (
-                                carry ^ jnp.uint8(1), body(carry, *a[1:])
-                            ),
-                            a[0], None, length=length,
-                        )[1]
-                    )
-                    r = looped(*args)
-                    jax.tree_util.tree_map(
-                        lambda x: x.block_until_ready(), r
-                    )  # compile
-                    t0 = time.perf_counter()
-                    r = looped(*args)
-                    jax.tree_util.tree_map(lambda x: x.block_until_ready(), r)
-                    return time.perf_counter() - t0
-
-                t1, t2 = loop(N1), loop(N2)
-                if t2 - t1 > 1e-6:
-                    out[metric] = round(
-                        (N2 - N1) * nbytes / 1e6 / (t2 - t1), 1
-                    )
-                else:
-                    out[f"{metric}_error"] = (
-                        f"timing jitter (t{N1}={t1:.3f}s, t{N2}={t2:.3f}s)"
-                    )
-            except Exception as e:
-                out[f"{metric}_error"] = str(e)[:160]
-
-        from s3shuffle_tpu.ops import crc_pallas
-
-        # Pallas probes run ONLY on a real TPU backend: off-TPU they would
-        # execute in interpret mode, which (a) is minutes-slow at probe size
-        # and (b) records emulation rates into the same cache the
-        # measured-rate gate (ops/rates.py) consults for dispatch.
-        # S3SHUFFLE_PROBE_PALLAS_CPU=1 overrides for manual interpret-mode
-        # smoke at reduced PROBE_L/B; tier-1 correctness coverage lives in
-        # tests/test_pallas_kernels.py.
-        pallas_interp = jax.default_backend() != "tpu"
-        run_pallas_probes = (
-            not pallas_interp
-            or os.environ.get("S3SHUFFLE_PROBE_PALLAS_CPU") == "1"
-        )
-        if run_pallas_probes:
-            _run_pallas_probes(
-                out, pallas_probe, pallas_interp, batch, dev, B * L,
-                crc_pallas, POLY_CRC32C,
+        gf_k = 8
+        gf_shape = (16, gf_k, B * L // (16 * gf_k))
+        gf_coefs = jnp.asarray(gf.parity_coefficients(2, gf_k))
+        try:
+            # built outside the traced loop: its log/exp tables would
+            # otherwise be the trace's tracers, cached past it
+            gf_kernel = gf._device_kernel(2, gf_k)
+            delta_rate(
+                lambda d: gf_kernel(d.reshape(gf_shape), gf_coefs),
+                "tpu_gf_encode_mb_s",
             )
+        except Exception as e:
+            out["tpu_gf_encode_mb_s_error"] = str(e)[:160]
 
         # decode correctness on-device: matches the staged input exactly
-        d = np.asarray(tlz._decode_kernel(n_groups)(dm, dc, ds, do, dk, dl))
+        d = np.asarray(tlz._decode_batch_kernel(B, n_groups, None)(
+            dm, dc, ds, do, dk, dl
+        )[0])
         if not (d == batch).all():
             out["tpu_probe_error"] = "device decode(encode(x)) != x"
             return out

@@ -243,9 +243,9 @@ def phase_b(parts) -> None:
     import jax
     import numpy as np
 
-    from s3shuffle_tpu.coding import gf, gf_pallas
-    from s3shuffle_tpu.ops import crc_pallas, tlz
-    from s3shuffle_tpu.ops.checksum import POLY_CRC32C, zero_run_crcs
+    from s3shuffle_tpu.coding import gf
+    from s3shuffle_tpu.ops import tlz
+    from s3shuffle_tpu.ops.checksum import POLY_CRC32C, crc32_batch
     from s3shuffle_tpu.utils.checksums import _crc32c_fn, crc32c_py
 
     crc = _crc32c_fn()
@@ -271,44 +271,37 @@ def phase_b(parts) -> None:
           "device decode differs from the input")
     results["tlz_decode"] = "identical"
 
-    for formulation in ("xla", "pallas"):
-        # the CRC inside the fused launches: XLA bit-matmul or the Pallas fold
-        os.environ["S3SHUFFLE_PALLAS_CRC"] = "1" if formulation == "pallas" else "0"
-        tlz._batch_kernel.cache_clear()
-        tlz._decode_batch_kernel.cache_clear()
-        payloads, (block_crcs, lit_crcs, lit_lens) = tlz.encode_batch_device(
-            blocks.tobytes(), ROWS, BLOCK, batch_blocks=ROWS, poly=POLY_CRC32C
-        )
-        check([bytes(p) for p in payloads] == host,
-              f"fused tlz encode ({formulation} CRC) differs from the host encoder")
-        check([int(c) for c in block_crcs] == [crc(r) for r in raw],
-              f"fused encode block CRCs ({formulation}) wrong")
-        check([int(c) for c in lit_crcs] == [
-            crc(bytes(p)[len(p) - int(n):]) for p, n in zip(payloads, lit_lens)
-        ], f"fused encode literal CRCs ({formulation}) wrong")
-        results[f"tlz_encode_fused_{formulation}_crc"] = "identical"
+    payloads, (block_crcs, lit_crcs, lit_lens) = tlz.encode_batch_device(
+        blocks.tobytes(), ROWS, BLOCK, batch_blocks=ROWS, poly=POLY_CRC32C
+    )
+    check([bytes(p) for p in payloads] == host,
+          "fused tlz encode differs from the host encoder")
+    check([int(c) for c in block_crcs] == [crc(r) for r in raw],
+          "fused encode block CRCs wrong")
+    check([int(c) for c in lit_crcs] == [
+        crc(bytes(p)[len(p) - int(n):]) for p, n in zip(payloads, lit_lens)
+    ], "fused encode literal CRCs wrong")
+    results["tlz_encode_fused"] = "identical"
 
-        dec, crcs = tlz.decode_batch_device(enc, [BLOCK] * len(enc), BLOCK,
-                                            batch_rows=ROWS, poly=POLY_CRC32C)
-        check([bytes(d) for d in dec] == [raw[i] for i in tlz_rows],
-              f"fused decode ({formulation} CRC) differs from the input")
-        check(all(c is not None and int(c) == crc(e) for c, e in zip(crcs, enc)),
-              f"fused decode CRCs ({formulation}) wrong")
-        results[f"tlz_decode_fused_{formulation}_crc"] = "identical"
-    os.environ.pop("S3SHUFFLE_PALLAS_CRC")
+    dec, crcs = tlz.decode_batch_device(enc, [BLOCK] * len(enc), BLOCK,
+                                        batch_rows=ROWS, poly=POLY_CRC32C)
+    check([bytes(d) for d in dec] == [raw[i] for i in tlz_rows],
+          "fused decode differs from the input")
+    check(all(c is not None and int(c) == crc(e) for c, e in zip(crcs, enc)),
+          "fused decode CRCs wrong")
+    results["tlz_decode_fused"] = "identical"
 
-    rem = np.asarray(crc_pallas.crc_raw_batch(jax.device_put(blocks), POLY_CRC32C))
-    zero = int(zero_run_crcs(POLY_CRC32C, BLOCK)[BLOCK])
-    check([int(r) ^ zero for r in rem] == [crc32c_py(r) for r in raw],
-          "crc_pallas.crc_raw_batch differs from crc32c_py")
-    results["crc32c_pallas"] = "identical"
+    got = crc32_batch(blocks, [BLOCK] * ROWS, POLY_CRC32C)
+    check([int(c) for c in got] == [crc32c_py(r) for r in raw],
+          "crc32_batch differs from crc32c_py")
+    results["crc32c"] = "identical"
 
     chunks = blocks.reshape(4, 4, ROWS * BLOCK // 16)  # G=4, k=4, 1 MiB lanes
     coefs = gf.parity_coefficients(2, 4)
-    got = gf_pallas.encode_groups_pallas(chunks, coefs, interpret=False)
+    got = gf._device_kernel(2, 4)(jax.device_put(chunks), jax.device_put(coefs))
     check(np.array_equal(np.asarray(got), gf._encode_host(chunks, coefs)),
-          "gf_pallas parity differs from the numpy table encoder")
-    results["gf_parity_pallas"] = "identical"
+          "gf table-kernel parity differs from the numpy table encoder")
+    results["gf_parity"] = "identical"
     observe("phase_b_kernels", results)
 
 

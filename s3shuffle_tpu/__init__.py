@@ -13,7 +13,7 @@ Capability parity map (reference file → this package):
 - ``S3BufferedPrefetchIterator`` → :mod:`s3shuffle_tpu.read.prefetch`
 - ``S3ChecksumValidationStream`` → :mod:`s3shuffle_tpu.read.checksum_stream`
 
-TPU-first additions the reference lacks: batched Pallas/XLA codec kernels
+TPU-first additions the reference lacks: batched XLA codec kernels
 (:mod:`s3shuffle_tpu.ops`), a C++ native CPU codec (:mod:`s3shuffle_tpu.codec`),
 an ICI all-to-all repartition fast path (:mod:`s3shuffle_tpu.parallel`), and a
 typed metrics subsystem with per-shuffle stats reports

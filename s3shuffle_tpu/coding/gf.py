@@ -173,11 +173,7 @@ def _encode_striped(
     to the unstriped kernel (pure per-group math)."""
     import jax
 
-    from s3shuffle_tpu.coding import gf_pallas
-
     m, k = coefs.shape
-    use_pallas = gf_pallas.supported(m, k)
-    interpret = jax.default_backend() != "tpu"
     groups = chunks.shape[0]
     n_lanes = max(1, min(disp.n_devices, groups))
     bounds = np.linspace(0, groups, n_lanes + 1).astype(np.int64)
@@ -188,25 +184,15 @@ def _encode_striped(
             lo, hi = int(bounds[i]), int(bounds[i + 1])
             if lo == hi:
                 continue
-            slot = disp.acquire("gf_encode")
+            slot = disp.acquire()
             slots.append(slot)
             dev = disp.device(slot)
-            if use_pallas:
-                # the constant-select Pallas kernel (no table gathers);
-                # interpret mode keeps it byte-exact off-chip
-                with jax.default_device(dev):
-                    outs.append(
-                        gf_pallas.encode_groups_pallas(
-                            chunks[lo:hi], coefs, interpret
-                        )
-                    )
-            else:
-                outs.append(
-                    _device_kernel(m, k)(
-                        jax.device_put(chunks[lo:hi], dev),
-                        jax.device_put(coefs, dev),
-                    )
+            outs.append(
+                _device_kernel(m, k)(
+                    jax.device_put(chunks[lo:hi], dev),
+                    jax.device_put(coefs, dev),
                 )
+            )
         # materialize AFTER every lane launched: the table-kernel slices run
         # concurrently across their devices and drain in order
         parts = [np.asarray(o) for o in outs]
@@ -225,17 +211,8 @@ def _encode_device(chunks: np.ndarray, coefs: np.ndarray) -> Optional[np.ndarray
     try:
         m, k = coefs.shape
         disp = _mesh_dispatcher()
-        from s3shuffle_tpu.coding import gf_pallas
-
         if disp is not None:
             out = _encode_striped(chunks, coefs, disp)
-        elif gf_pallas.supported(m, k):
-            # the constant-select Pallas kernel (no table gathers);
-            # interpret mode keeps it byte-exact off-chip
-            import jax
-
-            interpret = jax.default_backend() != "tpu"
-            out = gf_pallas.encode_groups_pallas(chunks, coefs, interpret)
         else:
             out = np.asarray(_device_kernel(m, k)(chunks, coefs))
         rates.count_device_batch("parity", True)

@@ -7,7 +7,7 @@ here match the reference's defaults exactly (SURVEY.md §5.6 flag table).
 
 TPU-first additions: ``codec`` / ``codec_block_size`` / ``codec_batch_blocks``
 / ``encode_inflight_batches`` select and tune the block codec (none / zlib /
-zstd / native C++ / TPU Pallas), which replaces the JVM codec streams
+zstd / native C++ / TPU), which replaces the JVM codec streams
 (``spark.io.compression.*``) the reference delegates to Spark.
 """
 

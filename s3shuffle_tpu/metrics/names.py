@@ -130,7 +130,7 @@ KNOWN_METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "codec_decode_bytes_total": ("counter", ()),
     "codec_decode_inflight": ("gauge", ()),
     "codec_fused_crc_validated_total": ("counter", ()),
-    # --- codec plane: measured-rate gate + Pallas kernels (ops/rates.py) ---
+    # --- codec plane: measured-rate gate (ops/rates.py) ---
     "codec_path_selected_total": ("counter", ("path", "reason")),
     "codec_kernel_compile_seconds": ("histogram", ("kernel",)),
     "codec_device_batches_total": ("counter", ("op", "outcome")),

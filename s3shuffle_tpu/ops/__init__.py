@@ -1,4 +1,4 @@
-"""TPU ops: batched checksum and compression kernels (XLA + Pallas).
+"""TPU ops: batched checksum and compression kernels (XLA graphs).
 
 These replace the JVM codec/checksum byte loops of the reference
 (java.util.zip via S3ShuffleHelper.createChecksumAlgorithm,

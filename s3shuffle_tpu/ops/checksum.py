@@ -218,18 +218,12 @@ def crc32_batch(blocks, lengths, poly: int = POLY_CRC32C, block_len: int | None 
     """
     blocks = np.ascontiguousarray(blocks, dtype=np.uint8)
     lengths = np.asarray(lengths, dtype=np.int64)
-    b, length = blocks.shape
+    length = blocks.shape[1]
     if block_len is not None and block_len != length:
         raise ValueError(f"block_len {block_len} != staged width {length}")
     _, zero_crc = _weights.get(poly, length)
-    if _use_pallas(b, length):
-        from s3shuffle_tpu.ops import crc_pallas
-
-        x = np.asarray(crc_pallas.crc_raw_batch(blocks, poly))
-    else:
-        w_bits = _device_weights(poly, length)  # cached on-device, shipped once
-        kernel = _crc_kernel(length)
-        x = np.asarray(kernel(blocks, w_bits))  # raw remainders, zero-init
+    w_bits = _device_weights(poly, length)  # cached on-device, shipped once
+    x = np.asarray(_crc_kernel(length)(blocks, w_bits))  # raw remainders, zero-init
     return (x ^ zero_crc[lengths]).astype(np.uint32)
 
 
@@ -266,72 +260,21 @@ def zero_run_crcs(poly: int, length: int) -> np.ndarray:
     return zero_crc
 
 
-def raw_crc_graph_fn(poly: int, length: int, batch: int):
-    """``(fn, tables)``: a traceable ``fn(data_u8, *tables(device)) -> (B,)
-    uint32`` raw zero-init remainder op for right-aligned ``(batch, length)``
-    rows, safe to call INSIDE a larger jit trace — the hook the fused TLZ
-    kernels use to fold the CRC pass into their own launch — and
-    ``tables(device)``, its constant tables resident on ``device``, shipped
-    once per (poly, device). Callers pass the tables as jit ARGUMENTS placed
-    on the launch's own device: closed over, the bit-matmul's (8L, 32)
-    weight table (64 MiB at 256 KiB blocks) is constant-folded into every
+def raw_crc_graph_fn(poly: int, length: int):
+    """``(fn, tables)``: a traceable ``fn(data_u8, w_bits) -> (B,) uint32``
+    raw zero-init remainder op (the MXU bit-matmul) for right-aligned
+    ``(B, length)`` rows, safe to call INSIDE a larger jit trace — the
+    hook the fused TLZ kernels use to fold the CRC pass into their own
+    launch — and ``tables(device)``, its ``(w_bits,)`` weight table resident
+    on ``device``, shipped once per (poly, device). Callers pass the table
+    as a jit ARGUMENT placed on the launch's own device: closed over, the
+    (8L, 32) table (64 MiB at 256 KiB blocks) is constant-folded into every
     program, ~45 s of TPU compile per launch shape; placed on another chip,
-    it is copied over on every launch. Picks the Pallas fold when enabled
-    and the shape tiles (:func:`_use_pallas`), else the MXU bit-matmul."""
-    if _use_pallas(batch, length):
-        from s3shuffle_tpu.ops import crc_pallas
-
-        def fn(data_u8, w_planes, fold_m):
-            return crc_pallas.crc_raw_in_graph(data_u8, (w_planes, fold_m))
-
-        return fn, lambda device: crc_pallas._device_tables(poly, device)
-
+    it is copied over on every launch."""
     def fn(data_u8, w_bits):
         return _crc_math(data_u8, w_bits, length)
 
     return fn, lambda device: (_device_weights(poly, length, device),)
-
-
-def _use_pallas(b: int, length: int) -> bool:
-    """Pallas tiled-fold kernel vs the XLA bit-matmul, inside device traces.
-
-    ``S3SHUFFLE_PALLAS_CRC=1`` forces the Pallas kernel, any other value
-    forces the XLA lowering; unset, the measured-rate table decides
-    (ops/rates.py): Pallas arms only when the last chip probe clocked
-    ``tpu_crc32c_pallas_mb_s`` above the XLA ``tpu_crc32c_mb_s`` — no probe
-    data keeps the (working) XLA path. Either way the kernel requires an
-    actual TPU backend and tileable shapes (CI proves it byte-identical in
-    interpret mode through :func:`crc_pallas.crc_raw_batch` directly)."""
-    import os
-
-    from s3shuffle_tpu.ops import rates
-
-    env = os.environ.get("S3SHUFFLE_PALLAS_CRC")
-    if env is not None:
-        if env.strip() != "1":
-            rates.record_selection("xla", "env-crc")
-            return False
-        reason = "env-crc"
-    else:
-        pallas_rate = rates.rate("tpu_crc32c_pallas_mb_s")
-        xla_rate = rates.rate("tpu_crc32c_mb_s")
-        if pallas_rate is None:
-            rates.record_selection("xla", "no-data")
-            return False
-        if xla_rate is not None and pallas_rate <= xla_rate:
-            rates.record_selection("xla", "measured-host")
-            return False
-        reason = "measured-device"
-    import jax
-
-    from s3shuffle_tpu.ops import crc_pallas
-
-    if jax.default_backend() != "tpu":
-        return False
-    if not crc_pallas.supported(b, length):
-        return False
-    rates.record_selection("pallas", reason)
-    return True
 
 
 @functools.lru_cache(maxsize=32)

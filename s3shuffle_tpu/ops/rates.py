@@ -25,10 +25,8 @@ Host reference rates default to conservative figures measured on the bench
 rig (``DEFAULT_HOST_RATES``); a cache file may override them with measured
 ``host_*`` fields when the probe records them.
 
-Callers: ``codec/tpu.py`` (encode/decode/fused routing),
-``ops/checksum.py`` (XLA vs Pallas CRC kernel selection inside fused
-traces), ``coding/gf.py`` (parity encode). Test injection:
-:func:`set_rates_for_testing`.
+Callers: ``codec/tpu.py`` (encode/decode/fused routing), ``coding/gf.py``
+(parity encode). Test injection: :func:`set_rates_for_testing`.
 """
 
 from __future__ import annotations
@@ -45,7 +43,7 @@ logger = logging.getLogger("s3shuffle_tpu.ops.rates")
 
 _C_SELECTED = _metrics.REGISTRY.counter(
     "codec_path_selected_total",
-    "Codec/checksum/parity path-selection decisions by outcome: path is the "
+    "Codec/parity path-selection decisions by outcome: path is the "
     "side chosen (device/host, or fused/streaming for the decode-validation "
     "route), reason says why (measured-device, measured-host, no-data, "
     "forced, env-device, env-host, gate-off)",
@@ -148,42 +146,17 @@ DEFAULT_HOST_RATES: Dict[str, float] = {
     "host_gf_encode_mb_s": 800.0,
 }
 
-#: op -> (device metric candidates, best wins; competing host metric).
-#: Pallas metrics are listed alongside the XLA formulations they replace —
-#: whichever measured best on THIS rig's last probe represents the device.
-OP_METRICS: Dict[str, Tuple[Tuple[str, ...], str]] = {
-    "encode": (
-        ("tpu_tlz_encode_mb_s",),
-        "host_tlz_encode_mb_s",
-    ),
-    "decode": (
-        ("tpu_tlz_decode_mb_s",),
-        "host_tlz_decode_mb_s",
-    ),
-    "crc": (
-        ("tpu_crc32c_pallas_mb_s", "tpu_crc32c_mb_s"),
-        "host_crc32c_mb_s",
-    ),
-    "gf_encode": (
-        ("tpu_gf_encode_mb_s",),
-        "host_gf_encode_mb_s",
-    ),
+#: op -> (device metric, competing host metric)
+OP_METRICS: Dict[str, Tuple[str, str]] = {
+    "encode": ("tpu_tlz_encode_mb_s", "host_tlz_encode_mb_s"),
+    "decode": ("tpu_tlz_decode_mb_s", "host_tlz_decode_mb_s"),
+    "gf_encode": ("tpu_gf_encode_mb_s", "host_gf_encode_mb_s"),
 }
-
-#: nested key of the rate cache carrying per-device-class tables for a
-#: heterogeneous fleet: ``{"device_classes": {"TPU v4": {...}, ...}}`` where
-#: each subtable holds the same metric fields as the top level and OVERRIDES
-#: it for devices of that class (tools/chip_gate.py gates each class
-#: independently; parallel/dispatch.py excludes classes whose measured
-#: rates lose to the host).
-DEVICE_CLASSES_KEY = "device_classes"
 
 _lock = threading.Lock()
 _cached: Optional[Dict[str, float]] = None
-_cached_classes: Optional[Dict[str, Dict[str, float]]] = None
 _cached_key: Optional[Tuple[str, float, int]] = None  # (path, mtime, size)
 _injected: Optional[Dict[str, float]] = None
-_injected_classes: Optional[Dict[str, Dict[str, float]]] = None
 
 
 def cache_path() -> str:
@@ -199,40 +172,12 @@ def cache_path() -> str:
 
 def set_rates_for_testing(table: Optional[Dict[str, float]]) -> None:
     """Inject a rate table (None restores file-backed lookup). Tests use
-    this to prove all three dispatch regimes without touching disk. A
-    ``device_classes`` entry (nested per-class tables) is split out and
-    served by :func:`class_table`."""
-    global _injected, _injected_classes, _cached, _cached_classes, _cached_key
+    this to prove all three dispatch regimes without touching disk."""
+    global _injected, _cached, _cached_key
     with _lock:
-        if table is None:
-            _injected, _injected_classes = None, None
-        else:
-            _injected = {
-                k: v for k, v in table.items() if k != DEVICE_CLASSES_KEY
-            }
-            _injected_classes = _parse_classes(table)
+        _injected = None if table is None else dict(table)
         _cached = None
-        _cached_classes = None
         _cached_key = None
-
-
-def _parse_classes(raw: Dict) -> Dict[str, Dict[str, float]]:
-    """The validated ``device_classes`` nesting of one raw cache dict:
-    class name -> numeric metric fields (non-numeric members dropped, like
-    the top level)."""
-    nested = raw.get(DEVICE_CLASSES_KEY)
-    if not isinstance(nested, dict):
-        return {}
-    out: Dict[str, Dict[str, float]] = {}
-    for kind, sub in nested.items():
-        if not isinstance(sub, dict):
-            continue
-        out[str(kind)] = {
-            k: float(v)
-            for k, v in sub.items()
-            if isinstance(v, (int, float)) and not isinstance(v, bool)
-        }
-    return out
 
 
 def invalidate() -> None:
@@ -244,7 +189,7 @@ def invalidate() -> None:
 def snapshot() -> Dict[str, float]:
     """Numeric fields of the rate cache (injected table, else the JSON file;
     missing/corrupt file = empty). Cached per (path, mtime, size)."""
-    global _cached, _cached_classes, _cached_key
+    global _cached, _cached_key
     with _lock:
         if _injected is not None:
             return dict(_injected)
@@ -253,7 +198,7 @@ def snapshot() -> Dict[str, float]:
             st = os.stat(path)
             key = (path, st.st_mtime, st.st_size)
         except OSError:
-            _cached, _cached_classes, _cached_key = {}, {}, None
+            _cached, _cached_key = {}, None
             return {}
         if _cached is not None and _cached_key == key:
             return dict(_cached)
@@ -269,71 +214,15 @@ def snapshot() -> Dict[str, float]:
             for k, v in raw.items()
             if isinstance(v, (int, float)) and not isinstance(v, bool)
         }
-        classes = _parse_classes(raw)
     except (OSError, ValueError) as exc:
         logger.warning("unreadable rate cache %s: %s — device paths "
                        "stay host-gated", path, exc)
-        table, classes = {}, {}
+        table = {}
     with _lock:
         if _injected is not None:  # a test swapped tables mid-read
             return dict(_injected)
-        _cached, _cached_classes, _cached_key = table, classes, key
+        _cached, _cached_key = table, key
     return dict(table)
-
-
-def class_table() -> Dict[str, Dict[str, float]]:
-    """Per-device-class rate tables (``device_classes`` in the cache /
-    injected table): class name -> metric fields that OVERRIDE the
-    top-level table for devices of that class. Empty on homogeneous rigs
-    whose probes never recorded class data."""
-    with _lock:
-        if _injected is not None:
-            return {k: dict(v) for k, v in (_injected_classes or {}).items()}
-        cached = _cached_classes
-    if cached is None:
-        snapshot()  # populate the per-file cache (classes ride along)
-        with _lock:
-            cached = _cached_classes
-    return {k: dict(v) for k, v in (cached or {}).items()}
-
-
-def class_armed(op: str, device_class: str, *, forced: bool = False) -> bool:
-    """Should devices of ``device_class`` take part in ``op`` placement?
-
-    The dispatcher-side half of the heterogeneous-fleet gate
-    (parallel/dispatch.py): a class with NO class-specific probe data for
-    the op stays armed — the caller's top-level :func:`select` already
-    chose the device side, and absence of evidence must not strand a
-    homogeneous fleet. A class WITH data is armed only when its merged
-    table (top-level fields overridden by the class subtable) still beats
-    the competing host rate, so a probe that measured one slow device
-    class can never arm it just because a faster class carried the
-    top-level verdict."""
-    mode = gate_mode()
-    if mode in ("device", "off"):
-        return True
-    if mode == "host":
-        return False
-    if forced:
-        return True
-    sub = class_table().get(device_class)
-    if not sub:
-        return True
-    device_metrics, host_metric = OP_METRICS[op]
-    if not any(m in sub for m in device_metrics):
-        return True  # no class-specific evidence for this op
-    merged = {**snapshot(), **sub}
-    dev_vals = [
-        float(merged[m])
-        for m in device_metrics
-        if isinstance(merged.get(m), (int, float)) and merged[m] > 0
-    ]
-    if not dev_vals:
-        return False  # class data exists but is unusable — stay honest
-    host = merged.get(host_metric)
-    if not (isinstance(host, (int, float)) and host > 0):
-        host = DEFAULT_HOST_RATES.get(host_metric, float("inf"))
-    return max(dev_vals) > float(host)
 
 
 def rate(metric: str) -> Optional[float]:
@@ -343,11 +232,6 @@ def rate(metric: str) -> Optional[float]:
     if val is None or not val > 0:
         return None
     return float(val)
-
-
-def best_rate(*metrics: str) -> Optional[float]:
-    vals = [r for r in (rate(m) for m in metrics) if r is not None]
-    return max(vals) if vals else None
 
 
 def host_rate(metric: str) -> float:
@@ -391,8 +275,8 @@ def decide(op: str, *, forced: bool = False) -> Tuple[bool, str]:
         return True, "gate-off"
     if forced:
         return True, "forced"
-    device_metrics, host_metric = OP_METRICS[op]
-    dev = best_rate(*device_metrics)
+    device_metric, host_metric = OP_METRICS[op]
+    dev = rate(device_metric)
     if dev is None:
         return False, "no-data"
     if dev > host_rate(host_metric):

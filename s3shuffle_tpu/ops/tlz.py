@@ -461,10 +461,10 @@ def _batch_kernel(batch_rows: int, n_groups: int, poly: Optional[int]):
         ))
     from s3shuffle_tpu.ops.checksum import raw_crc_graph_fn
 
-    crc_fn, tables = raw_crc_graph_fn(poly, n_groups * GROUP, 2 * batch_rows)
+    crc_fn, tables = raw_crc_graph_fn(poly, n_groups * GROUP)
     kernel = jax.jit(
-        lambda blocks, *t: _encode_fused_math(
-            blocks, n_groups, lambda d: crc_fn(d, *t)
+        lambda blocks, w_bits: _encode_fused_math(
+            blocks, n_groups, lambda d: crc_fn(d, w_bits)
         ),
         donate_argnums=(0,),
     )
@@ -666,7 +666,7 @@ def encode_batch_device(
         for s in range(0, n_blocks, cap):
             e = min(n_blocks, s + cap)
             rows = _bucket_rows(e - s, cap)
-            slot = disp.acquire("encode") if disp is not None else 0
+            slot = disp.acquire() if disp is not None else 0
             while any(p[2] == slot for p in pending):
                 # the lane's previous launch may still be reading its
                 # device_put-aliased staging plane — drain until the lane is
@@ -1134,20 +1134,6 @@ def _unpack_bits_math(bitmap_u8, n_groups: int):
     return bits.reshape(bitmap_u8.shape[0], n_groups).astype(bool)
 
 
-def _decode_math(
-    is_match, is_cont, is_split, offs_padded, ks_padded, lits_padded,
-    n_groups: int,
-):
-    """The raw (unjitted) decode computation — shared by the standalone
-    jitted kernel and larger fused traces (e.g. the multichip dryrun's
-    in-graph encode→decode roundtrip check): the decoded (B, n_bytes)
-    uint8 rows of :func:`_decode_counted_math`."""
-    return _decode_counted_math(
-        is_match, is_cont, is_split, offs_padded, ks_padded, lits_padded,
-        n_groups,
-    )[0]
-
-
 def _decode_counted_math(
     is_match, is_cont, is_split, offs_padded, ks_padded, lits_padded,
     n_groups: int,
@@ -1406,7 +1392,7 @@ def _decode_fused_math(
     n_lits, n_groups: int, crc_fn,
 ):
     """Decode + fused CRC in ONE trace: the decoded rows of
-    :func:`_decode_math` plus, from the same launch, the raw zero-init CRC
+    :func:`_decode_counted_math` plus, from the same launch, the raw zero-init CRC
     remainder of each row's literal plane right-aligned — the dominant slice
     of every stored TLZ payload. The host stitches the small header/metadata
     prefix CRCs around it with :func:`ops.checksum.crc_combine`, so the read
@@ -1433,25 +1419,6 @@ def _decode_fused_math(
     return decoded, crc_fn(lits_right), rounds
 
 
-@functools.lru_cache(maxsize=8)
-def _decode_kernel(n_groups: int):
-    """Batched device decoder: fixed-shape inputs (padded); pointer-jumping
-    gathers until the launch's chains resolve. Kept as
-    the variable-batch entry for fused traces and the bench; the read plane
-    routes through :func:`_decode_batch_kernel` (fixed batch rows, donated
-    staging — no retrace per distinct batch size)."""
-    jax, _jnp = _jax()
-
-    @jax.jit
-    def kernel(is_match, is_cont, is_split, offs_padded, ks_padded, lits_padded):
-        return _decode_math(
-            is_match, is_cont, is_split, offs_padded, ks_padded, lits_padded,
-            n_groups,
-        )
-
-    return kernel
-
-
 @functools.lru_cache(maxsize=16)
 def _decode_batch_kernel(batch_rows: int, n_groups: int, poly: Optional[int]):
     """Precompiled fixed-shape batched decode kernel — one trace per
@@ -1471,10 +1438,10 @@ def _decode_batch_kernel(batch_rows: int, n_groups: int, poly: Optional[int]):
         ))
     from s3shuffle_tpu.ops.checksum import raw_crc_graph_fn
 
-    crc_fn, tables = raw_crc_graph_fn(poly, n_groups * GROUP, batch_rows)
+    crc_fn, tables = raw_crc_graph_fn(poly, n_groups * GROUP)
     kernel = jax.jit(
-        lambda m, c, s, o, k, lits, n_lits, *t: _decode_fused_math(
-            m, c, s, o, k, lits, n_lits, n_groups, lambda d: crc_fn(d, *t)
+        lambda m, c, s, o, k, lits, n_lits, w_bits: _decode_fused_math(
+            m, c, s, o, k, lits, n_lits, n_groups, lambda d: crc_fn(d, w_bits)
         ),
         donate_argnums=(0, 1, 2, 3, 4, 5),
     )
@@ -1746,7 +1713,7 @@ def decode_batch_device(
                     # skip the kernel
                     continue
                 launch_rows = _bucket_rows(e - s, cap)
-                slot = disp.acquire("decode") if disp is not None else 0
+                slot = disp.acquire() if disp is not None else 0
                 while any(p[3] == slot for p in pending):
                     # the lane's previous launch may still be reading its
                     # device_put-aliased staging planes — drain until the

@@ -9,14 +9,9 @@ how many chips the host had. The dispatcher is the placement layer under
 those executors:
 
 - **least-outstanding-work placement**: :meth:`DeviceDispatcher.acquire`
-  picks the eligible device with the fewest launches in flight (ties go to
-  the lowest index, so a single-stream caller still walks devices
+  picks the device with the fewest launches in flight (ties go to the
+  lowest index, so a single-stream caller still walks devices
   round-robin);
-- **per-device-class rate gate**: a heterogeneous fleet may carry probe data
-  per device class (``device_classes`` in the rate cache — ops/rates.py);
-  classes whose measured rates lose to the host for an op are excluded from
-  placement, so one slow device class can never arm itself into the batch
-  path;
 - **per-device accounting**: ``mesh_batches_dispatched_total{device}``,
   the ``mesh_device_outstanding{device}`` gauge, and
   ``mesh_dispatch_wait_seconds`` (time a full in-flight window spent
@@ -40,7 +35,7 @@ import logging
 import os
 import sys
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import List, Optional
 
 from s3shuffle_tpu.metrics import registry as _metrics
 
@@ -77,9 +72,8 @@ _built_for: Optional[int] = None
 class DeviceDispatcher:
     """Least-outstanding-work placement over a fixed device tuple.
 
-    Thread-safe: the per-device outstanding counters and the per-op
-    eligibility cache are only touched under ``_lock`` (the race-witness
-    dispatcher units watch both fields).
+    Thread-safe: the per-device outstanding counters are only touched
+    under ``_lock`` (the race-witness dispatcher units watch them).
     """
 
     def __init__(self, devices):
@@ -90,14 +84,8 @@ class DeviceDispatcher:
             f"{getattr(d, 'platform', 'dev')}:{getattr(d, 'id', i)}"
             for i, d in enumerate(self.devices)
         )
-        self._kinds = tuple(
-            str(getattr(d, "device_kind", None)
-                or getattr(d, "platform", "unknown"))
-            for d in self.devices
-        )
         self._lock = threading.Lock()
         self._outstanding: List[int] = [0] * len(self.devices)
-        self._eligible: Dict[str, Tuple[int, ...]] = {}
 
     # ------------------------------------------------------------------
     @property
@@ -116,38 +104,13 @@ class DeviceDispatcher:
         return len(self.devices)
 
     # ------------------------------------------------------------------
-    def _eligible_for(self, op: str) -> Tuple[int, ...]:
-        """Device indices whose device CLASS the measured-rate table arms
-        for ``op`` (computed once per op; callers hold ``_lock``). A class
-        with no class-specific probe data stays eligible — the caller's
-        top-level rate gate already chose the device side. If every class
-        is gated out, all devices stay eligible rather than stranding the
-        launch (the top-level verdict wins)."""
-        cached = self._eligible.get(op)
-        if cached is not None:
-            return cached
-        from s3shuffle_tpu.ops import rates
-
-        armed = {kind: rates.class_armed(op, kind) for kind in set(self._kinds)}
-        eligible = tuple(
-            i for i, kind in enumerate(self._kinds) if armed[kind]
-        ) or tuple(range(len(self.devices)))
-        if len(eligible) < len(self.devices):
-            gated = sorted(k for k, ok in armed.items() if not ok)
-            logger.info(
-                "mesh dispatcher: device class(es) %s rate-gated out of %s "
-                "placement", ", ".join(gated), op,
-            )
-        self._eligible[op] = eligible
-        return eligible
-
-    def acquire(self, op: str = "encode") -> int:
-        """Pick the eligible device with the fewest launches in flight and
-        claim one slot on it. Returns the device index (pair every acquire
-        with a :meth:`release`)."""
+    def acquire(self) -> int:
+        """Pick the device with the fewest launches in flight and claim one
+        slot on it. Returns the device index (pair every acquire with a
+        :meth:`release`)."""
         with self._lock:
-            eligible = self._eligible_for(op)
-            idx = min(eligible, key=lambda i: (self._outstanding[i], i))
+            idx = min(range(len(self.devices)),
+                      key=lambda i: (self._outstanding[i], i))
             self._outstanding[idx] += 1
             now = self._outstanding[idx]
         if _metrics.enabled():

@@ -29,5 +29,5 @@ BUILD_INFO = {
     "name": "s3shuffle_tpu",
     "version": __version__,
     "shuffle_format": SHUFFLE_FORMAT_VERSION,
-    "target": "tpu (jax/xla/pallas) + cpu fallback",
+    "target": "tpu (jax/xla) + cpu fallback",
 }

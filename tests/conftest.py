@@ -7,8 +7,8 @@ path via __graft_entry__.dryrun_multichip).
 
 import os
 
-# The test suite always runs on a virtual 8-device CPU mesh, Pallas kernels
-# in interpret mode; tests/test_chip_compile.py compiles for a described v5e.
+# The test suite always runs on a virtual 8-device CPU mesh;
+# tests/test_chip_compile.py compiles for a described v5e.
 os.environ["JAX_PLATFORMS"] = "cpu"
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:

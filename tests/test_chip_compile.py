@@ -1,9 +1,9 @@
 """Compile the device kernels of the main path for a described TPU v5e.
 
 The TPU compiler is installed here and compiles for a chip that is
-described, not attached: these tests catch what interpret mode cannot (block
-shapes the Mosaic tiling refuses, VMEM overruns, collectives the
-partitioner cannot place) at no chip time. Nothing runs, so they say nothing
+described, not attached: these tests catch what the CPU backend cannot (TPU
+layouts, device memory limits, collectives the partitioner cannot place)
+at no chip time. Nothing runs, so they say nothing
 about results or speed. The topology is described inside a module fixture —
 never at import — so every xdist worker collects the same tests and only the
 worker given this file loads the TPU library.
@@ -13,6 +13,7 @@ picks its directory.
 """
 
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -91,71 +92,95 @@ def _decode_args(sharding, rows):
 def test_crc_fold_pallas_compiles_at_fused_encode_width(one_chip):
     import jax.numpy as jnp
 
-    from s3shuffle_tpu.ops import crc_pallas
+    from s3shuffle_tpu.ops import checksum
 
     b = 2 * ROWS  # the fused encode CRCs raw blocks AND literal planes
+    fn, _tables = checksum.raw_crc_graph_fn(POLY_CRC32C, BLOCK)
     compiled = _compile(
-        crc_pallas._fold_pallas(b, BLOCK, False),
+        fn,
         _arg(one_chip, (b, BLOCK), jnp.uint8),
-        _arg(one_chip, (8, 32, crc_pallas._TL), jnp.int8),
-        _arg(one_chip, (32, 32), jnp.int8),
+        _arg(one_chip, (BLOCK * 8, 32), jnp.int8),
     )
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    # the int8 dot_general lowers to an MXU convolution with int32 output
+    assert re.search(
+        r"s32\[128,32\]\S* convolution\(.*op_name=\"[^\"]*dot_general", text
+    )
+    assert "s8[2097152,32]" in text
+    assert "tpu_custom_call" not in text
 
 
 def test_gf_parity_pallas_compiles(one_chip):
     import jax.numpy as jnp
 
-    from s3shuffle_tpu.coding import gf, gf_pallas
+    from s3shuffle_tpu.coding import gf
 
-    consts = gf_pallas._bit_constants(gf.parity_coefficients(2, 4))
     compiled = _compile(
-        gf_pallas._pallas_call(4, 1 << 20, consts, False),
+        gf._device_kernel(2, 4),
         _arg(one_chip, (4, 4, 1 << 20), jnp.uint8),
+        _arg(one_chip, (2, 4), jnp.uint8),
     )
-    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis() is not None
 
 
 def test_device_decode_compiles(one_chip):
     import functools
 
     compiled = _compile(
-        functools.partial(tlz._decode_math, n_groups=N_GROUPS),
+        functools.partial(tlz._decode_counted_math, n_groups=N_GROUPS),
         *_decode_args(one_chip, ROWS),
     )
     assert compiled.memory_analysis() is not None
 
 
-@pytest.mark.parametrize("crc", ["xla", "pallas"])
+@pytest.mark.parametrize("crc", ["xla"])  # the node id predates the one CRC
 def test_fused_decode_compiles(one_chip, crc):
     import jax.numpy as jnp
 
-    from s3shuffle_tpu.ops import checksum, crc_pallas
+    from s3shuffle_tpu.ops import checksum
 
-    # the CRC tables ride as arguments: as closure constants the XLA
+    # the CRC table rides as an argument: as a closure constant the
     # bit-matmul's 64 MiB weight table takes ~50 s of constant folding
-    if crc == "pallas":
-        tables = (_arg(one_chip, (8, 32, crc_pallas._TL), jnp.int8),
-                  _arg(one_chip, (32, 32), jnp.int8))
-
-        def crc_fn(data, w, fold):
-            return crc_pallas.crc_raw_in_graph(data, (w, fold))
-    else:
-        tables = (_arg(one_chip, (BLOCK * 8, 32), jnp.int8),)
-
-        def crc_fn(data, w):
-            return checksum._crc_math(data, w, BLOCK)
-
-    def fused(m, c, s, o, k, lits, n_lits, *t):
-        return tlz._decode_fused_math(m, c, s, o, k, lits, n_lits, N_GROUPS,
-                                      lambda data: crc_fn(data, *t))
+    def fused(m, c, s, o, k, lits, n_lits, w_bits):
+        return tlz._decode_fused_math(
+            m, c, s, o, k, lits, n_lits, N_GROUPS,
+            lambda data: checksum._crc_math(data, w_bits, BLOCK),
+        )
 
     compiled = _compile(
         fused, *_decode_args(one_chip, ROWS),
-        _arg(one_chip, (ROWS,), jnp.int32), *tables,
+        _arg(one_chip, (ROWS,), jnp.int32),
+        _arg(one_chip, (BLOCK * 8, 32), jnp.int8),
     )
-    if crc == "pallas":
-        assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis() is not None
+
+
+def test_fused_encode_program_holds_the_bit_matmul(one_chip, monkeypatch):
+    """The codec's own fused encode program at the terasort.r8 launch shape,
+    lowered (not compiled): its CRC is the int8 bit-matmul against the
+    weight-table argument, and nothing in it is a custom call."""
+    import jax
+    import jax.numpy as jnp
+
+    programs = []
+    real_jit = jax.jit
+
+    def recording_jit(*args, **kwargs):
+        programs.append(real_jit(*args, **kwargs))
+        return programs[-1]
+
+    monkeypatch.setattr(jax, "jit", recording_jit)
+    tlz._batch_kernel.__wrapped__(ROWS, N_GROUPS, POLY_CRC32C)
+    (program,) = programs
+    text = program.lower(
+        _arg(one_chip, (ROWS, BLOCK), jnp.uint8),
+        _arg(one_chip, (BLOCK * 8, 32), jnp.int8),
+    ).as_text()
+    assert re.search(
+        r"stablehlo\.dot_general .*%arg1.*: \(tensor<128x2097152xi8>, "
+        r"tensor<2097152x32xi8>\) -> tensor<128x32xi32>", text
+    )
+    assert "custom_call" not in text
 
 
 def test_tlz_xla_encode_compiles_at_block_width(one_chip):

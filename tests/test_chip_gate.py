@@ -36,7 +36,7 @@ def test_gate_fails_nonzero_on_regression(tmp_path, capsys):
 def test_gate_passes_when_kernels_beat_floors(tmp_path, capsys):
     cache = tmp_path / "rates.json"
     cache.write_text(json.dumps({
-        "tpu_crc32c_pallas_mb_s": 2000.0,
+        "tpu_crc32c_mb_s": 2000.0,
         "tpu_gf_encode_mb_s": 950.0,
         "tpu_tlz_decode_mb_s": 1004.2,
         "tpu_tlz_decode_fused_mb_s": 950.0,
@@ -72,7 +72,7 @@ def test_new_probe_fields_survive_merge():
     fresh = {
         "tpu_tlz_encode_fused_mb_s": 620.0,
         "tpu_tlz_encode_fused_cold_s": 4.1,
-        "tpu_crc32c_pallas_mb_s": 1900.0,
+        "tpu_crc32c_mb_s": 1900.0,
         "tpu_gf_encode_mb_s": 910.0,
     }
     merged = chip_gate.merge_probe_metrics(cached, fresh)
@@ -86,13 +86,13 @@ def test_new_probe_fields_survive_merge():
 
 
 def test_error_fields_never_erase_last_good():
-    cached = {"tpu_crc32c_pallas_mb_s": 1900.0, "old_error": "stale"}
+    cached = {"tpu_crc32c_mb_s": 1900.0, "old_error": "stale"}
     fresh = {
-        "tpu_crc32c_pallas_mb_s_error": "timing jitter",
+        "tpu_crc32c_mb_s_error": "timing jitter",
         "tpu_gf_encode_mb_s": 910.0,
     }
     merged = chip_gate.merge_probe_metrics(cached, fresh)
-    assert merged["tpu_crc32c_pallas_mb_s"] == 1900.0
+    assert merged["tpu_crc32c_mb_s"] == 1900.0
     assert merged["tpu_gf_encode_mb_s"] == 910.0
     assert not any(k.endswith("_error") for k in merged)
 

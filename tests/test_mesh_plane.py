@@ -12,9 +12,9 @@ Layers:
 - **op-for-op regression gate** — ``mesh_devices=0`` on the shared
   RecordingBackend must reproduce the pre-plane host pattern exactly: the
   same op multiset AND byte-identical blobs;
-- **dispatcher units** — least-outstanding-work placement, slot accounting,
-  and per-device-class eligibility, run under the PR-19 race witness with
-  ``watch_shared`` on the per-device queue state;
+- **dispatcher units** — least-outstanding-work placement and slot
+  accounting, run under the PR-19 race witness with ``watch_shared`` on the
+  per-device queue state;
 - **codec executors under the dispatcher** — encode/decode payload bytes at
   width 8 equal the disarmed single-device bytes.
 """
@@ -32,7 +32,6 @@ from s3shuffle_tpu.config import ShuffleConfig
 from s3shuffle_tpu.dependency import HashPartitioner, ShuffleDependency
 from s3shuffle_tpu.manager import ShuffleManager
 from s3shuffle_tpu.metrics import registry as mreg
-from s3shuffle_tpu.ops import rates
 from s3shuffle_tpu.parallel import dispatch
 from s3shuffle_tpu.shuffle import ShuffleContext
 from s3shuffle_tpu.storage.dispatcher import Dispatcher
@@ -257,10 +256,9 @@ def test_mesh_devices_zero_is_op_for_op_and_byte_identical(tmp_path):
 
 
 class _FakeDev:
-    def __init__(self, i, kind="FakeChip"):
+    def __init__(self, i):
         self.id = i
         self.platform = "fake"
-        self.device_kind = kind
 
 
 def test_dispatcher_least_outstanding_placement():
@@ -286,11 +284,11 @@ def test_dispatcher_queue_state_race_clean_under_witness():
     happens-before witness with zero reports."""
     with racewitness.quarantine() as q:
         disp = dispatch.DeviceDispatcher([_FakeDev(i) for i in range(3)])
-        disp = racewitness.watch_shared(disp, ("_outstanding", "_eligible"))
+        disp = racewitness.watch_shared(disp, ("_outstanding",))
 
         def storm():
             for _ in range(60):
-                idx = disp.acquire("encode")
+                idx = disp.acquire()
                 disp.release(idx)
 
         threads = [threading.Thread(target=storm) for _ in range(4)]
@@ -300,64 +298,6 @@ def test_dispatcher_queue_state_race_clean_under_witness():
             t.join()
         assert disp.outstanding_snapshot() == [0, 0, 0]
         assert not q.new_reports(), "\n".join(q.new_reports())
-
-
-def test_dispatcher_class_gating_excludes_slow_class():
-    """A device class whose measured rates lose to the host must be
-    excluded from placement; classes without class data stay eligible."""
-    rates.set_rates_for_testing({
-        "host_tlz_encode_mb_s": 400.0,
-        "tpu_tlz_encode_mb_s": 900.0,
-        "device_classes": {
-            "SlowChip": {"tpu_tlz_encode_mb_s": 3.0},
-            "FastChip": {"tpu_tlz_encode_mb_s": 2000.0},
-        },
-    })
-    try:
-        disp = dispatch.DeviceDispatcher(
-            [_FakeDev(0, "FastChip"), _FakeDev(1, "SlowChip"),
-             _FakeDev(2, "FastChip")]
-        )
-        taken = {disp.acquire("encode") for _ in range(6)}
-        assert 1 not in taken, "slow class must never be placed"
-        assert taken == {0, 2}
-    finally:
-        rates.set_rates_for_testing(None)
-
-
-def test_dispatcher_all_classes_gated_falls_back_to_all():
-    """If every class loses its class-level gate, placement falls back to
-    all devices — the caller's top-level rate gate already chose the device
-    side, and stranding the launch would deadlock the window."""
-    rates.set_rates_for_testing({
-        "host_tlz_encode_mb_s": 400.0,
-        "device_classes": {"OnlyChip": {"tpu_tlz_encode_mb_s": 3.0}},
-    })
-    try:
-        disp = dispatch.DeviceDispatcher(
-            [_FakeDev(0, "OnlyChip"), _FakeDev(1, "OnlyChip")]
-        )
-        assert {disp.acquire("encode") for _ in range(2)} == {0, 1}
-    finally:
-        rates.set_rates_for_testing(None)
-
-
-def test_class_armed_semantics():
-    rates.set_rates_for_testing({
-        "host_tlz_encode_mb_s": 400.0,
-        "device_classes": {
-            "Slow": {"tpu_tlz_encode_mb_s": 3.0},
-            "Fast": {"tpu_tlz_encode_mb_s": 2000.0},
-        },
-    })
-    try:
-        assert rates.class_armed("encode", "Fast") is True
-        assert rates.class_armed("encode", "Slow") is False
-        # no class data: the top-level verdict stands
-        assert rates.class_armed("encode", "Unknown") is True
-        assert rates.class_armed("encode", "Slow", forced=True) is True
-    finally:
-        rates.set_rates_for_testing(None)
 
 
 # ---------------------------------------------------------------------------
@@ -455,7 +395,6 @@ def test_fused_crc_tables_live_on_the_launch_device(monkeypatch, op):
     from s3shuffle_tpu.ops import checksum, tlz
     from s3shuffle_tpu.ops.checksum import POLY_CRC32C
 
-    monkeypatch.setenv("S3SHUFFLE_PALLAS_CRC", "0")  # the XLA weight table
     block, blocks, batch = 2048, 16, 4
     data = np.tile(np.arange(block, dtype=np.uint8) % 7, (blocks, 1))
     payloads, _ = tlz.encode_batch_device(

@@ -87,42 +87,3 @@ def test_combine_stitches_device_block_crcs():
     for i in range(1, len(blocks)):
         total = crc_combine(int(total), int(per_block[i]), len(blocks[i]), POLY_CRC32)
     assert total == (zlib.crc32(b"".join(blocks)) & 0xFFFFFFFF)
-
-
-def test_pallas_crc_matches_zlib_interpret_mode():
-    # The fused Pallas kernel (bit-planes never leave VMEM) must agree with
-    # zlib.crc32 for full and right-aligned short rows. Interpret mode runs
-    # the same kernel body on CPU.
-    import zlib
-
-    import numpy as np
-
-    from s3shuffle_tpu.ops import crc_pallas
-    from s3shuffle_tpu.ops.checksum import POLY_CRC32, _weights
-
-    rng = np.random.default_rng(7)
-    B, L = 128, 256
-    _w, zero_crc = _weights.get(POLY_CRC32, L)
-    data = np.zeros((B, L), dtype=np.uint8)
-    lens = rng.integers(0, L + 1, B)
-    for i in range(B):
-        data[i, L - lens[i] :] = rng.integers(0, 256, lens[i], dtype=np.uint8)
-    raw = np.asarray(crc_pallas.crc_raw_batch(data, POLY_CRC32, interpret=True))
-    full = (raw ^ zero_crc[lens]).astype(np.uint32)
-    expect = np.array(
-        [zlib.crc32(data[i, L - lens[i] :].tobytes()) for i in range(B)], dtype=np.uint32
-    )
-    assert (full == expect).all()
-
-
-def test_pallas_crc_shape_gate():
-    import numpy as np
-    import pytest
-
-    from s3shuffle_tpu.ops import crc_pallas
-    from s3shuffle_tpu.ops.checksum import POLY_CRC32
-
-    assert not crc_pallas.supported(100, 256)  # B not tile-aligned
-    assert not crc_pallas.supported(128, 100)  # L not tile-aligned
-    with pytest.raises(ValueError):
-        crc_pallas.crc_raw_batch(np.zeros((100, 256), np.uint8), POLY_CRC32, interpret=True)

@@ -1,16 +1,15 @@
 """Seeded property suites for the device codec kernels.
 
-On the CPU test mesh the Pallas kernels run in interpret mode
-(``jax.default_backend() != "tpu"``), which executes the exact same kernel
-program through the JAX interpreter — so CI proves byte-identity without a
-chip: the device TLZ encode against the numpy encoder (which makes the
-device graph's match decisions at every width; the host C encoder shares
-the wire format and agrees only at small blocks, ROADMAP D4), the fused
-decode+CRC against the host decode and native crc32c, the tiled CRC fold
-against the host raw remainder, and the GF(2^8) parity kernel against the
-numpy table encoder, plus mid-kernel failure falling back host-side without
-losing a frame (and counted). tests/test_chip_compile.py compiles the same
-kernels for a described v5e.
+On the CPU test mesh the device kernels run as the same XLA programs the
+chip runs, so CI proves byte-identity without a chip: the device TLZ
+encode against the numpy encoder (which makes the device graph's match
+decisions at every width; the host C encoder shares the wire format and
+agrees only at small blocks, ROADMAP D4), the fused decode+CRC against the
+host decode and native crc32c, the CRC32C bit-matmul against the host raw
+remainder, and the GF(2^8) parity table kernel against the numpy table
+encoder, plus mid-kernel failure falling back host-side without losing a
+frame (and counted). tests/test_chip_compile.py compiles the same kernels
+for a described v5e.
 """
 
 import numpy as np
@@ -19,8 +18,13 @@ import pytest
 import s3shuffle_tpu.codec.tpu as tpu_mod
 from s3shuffle_tpu.codec.tpu import TpuCodec
 from s3shuffle_tpu.metrics import registry as mreg
-from s3shuffle_tpu.ops import crc_pallas, tlz
-from s3shuffle_tpu.ops.checksum import POLY_CRC32C, _crc_raw_bytes
+from s3shuffle_tpu.ops import tlz
+from s3shuffle_tpu.ops.checksum import (
+    POLY_CRC32C,
+    _crc_raw_bytes,
+    crc32_batch,
+    raw_crc_graph_fn,
+)
 from s3shuffle_tpu.utils.checksums import crc32c_py
 
 
@@ -144,79 +148,77 @@ def test_device_fused_decode_roundtrip_and_crc(n_blocks):
 
 
 # ---------------------------------------------------------------------------
-# CRC32C tiled fold: every length/alignment, incl. right-aligned staging
+# CRC32C bit-matmul: every length/alignment, incl. right-aligned staging
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("b,length", [(8, 128), (8, 512), (16, 1280),
-                                      (24, 256)])
-def test_pallas_crc_matches_host_remainder(b, length):
+def _full_rows(b, length):
     rng = np.random.default_rng(b * length)
-    data = rng.integers(0, 256, (b, length), dtype=np.uint8)
-    got = crc_pallas.crc_raw_batch(data, POLY_CRC32C, interpret=True)
-    want = [_crc_raw_bytes(bytes(row), POLY_CRC32C, 0) & 0xFFFFFFFF
-            for row in data]
-    assert [int(c) for c in got] == want
+    return rng.integers(0, 256, (b, length), dtype=np.uint8), [length] * b
 
 
-@pytest.mark.parametrize("tail", [0, 1, 37, 127, 128, 300])
-def test_pallas_crc_right_aligned_rows(tail):
-    """The literal-plane form: rows are right-aligned with zero front
-    padding, which must be a fixed point of the fold (zero-init raw
-    remainder of zeros is zero) — the remainder equals the suffix's."""
+def _right_aligned_rows(tail):
+    """The literal-plane form: rows right-aligned with zero front padding,
+    which a zero-init raw remainder passes through unchanged."""
     length = 512
     rng = np.random.default_rng(tail)
     rows = np.zeros((8, length), dtype=np.uint8)
-    for i in range(8):
-        n = min(length, tail + i)
+    lens = [min(length, tail + i) for i in range(8)]
+    for i, n in enumerate(lens):
         if n:
             rows[i, length - n:] = rng.integers(0, 256, n, dtype=np.uint8)
-    got = crc_pallas.crc_raw_batch(rows, POLY_CRC32C, interpret=True)
-    want = [
-        _crc_raw_bytes(bytes(row[length - min(length, tail + i):]),
-                       POLY_CRC32C, 0) & 0xFFFFFFFF
-        for i, row in enumerate(rows)
+    return rows, lens
+
+
+@pytest.mark.parametrize("staged", [
+    *(pytest.param(lambda b=b, n=n: _full_rows(b, n), id=f"{b}-{n}")
+      for b, n in [(8, 128), (8, 512), (16, 1280), (24, 256)]),
+    *(pytest.param(lambda t=t: _right_aligned_rows(t), id=f"tail-{t}")
+      for t in [0, 1, 37, 127, 128, 300]),
+])
+def test_pallas_crc_matches_host_remainder(staged):
+    """The in-graph hook the fused kernels call returns each row's raw
+    zero-init remainder; the standalone batch returns the true CRC32C."""
+    import jax
+
+    rows, lens = staged()
+    length = rows.shape[1]
+    suffixes = [bytes(row[length - n:]) for row, n in zip(rows, lens)]
+    fn, tables = raw_crc_graph_fn(POLY_CRC32C, length)
+    raw = jax.jit(fn)(rows, *tables(None))
+    assert [int(c) for c in raw] == [
+        _crc_raw_bytes(s, POLY_CRC32C, 0) & 0xFFFFFFFF for s in suffixes
     ]
-    assert [int(c) for c in got] == want
-
-
-def test_pallas_crc_rejects_untileable_shapes():
-    assert not crc_pallas.supported(7, 128)   # rows not 8-tileable
-    assert not crc_pallas.supported(8, 100)   # length not 128-tileable
-    assert not crc_pallas.supported(0, 128)
-    with pytest.raises(ValueError):
-        crc_pallas.crc_raw_batch(
-            np.zeros((7, 128), np.uint8), POLY_CRC32C, interpret=True
-        )
+    full = crc32_batch(rows, lens, POLY_CRC32C)
+    assert [int(c) for c in full] == [crc32c_py(s) for s in suffixes]
 
 
 # ---------------------------------------------------------------------------
-# GF(2^8) parity kernel vs the numpy table encoder, with recovery
+# GF(2^8) parity table kernel vs the numpy table encoder, with recovery
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("m,k", [(1, 2), (1, 16), (2, 4), (2, 8), (3, 5),
                                  (4, 16), (8, 64)])
 def test_pallas_gf_matches_numpy(m, k):
-    from s3shuffle_tpu.coding import gf, gf_pallas
+    from s3shuffle_tpu.coding import gf
 
-    assert gf_pallas.supported(m, k)
     rng = np.random.default_rng(m * 100 + k)
     chunks = rng.integers(0, 256, (3, k, 100), dtype=np.uint8)  # odd G and L
     coefs = gf.parity_coefficients(m, k)
-    got = gf_pallas.encode_groups_pallas(chunks, coefs, interpret=True)
+    got = np.asarray(gf._device_kernel(m, k)(chunks, coefs))
     assert got.shape == (3, m, 100)
     assert np.array_equal(got, gf._encode_host(chunks, coefs))
 
 
 def test_pallas_gf_parity_recovers_erased_chunks():
-    from s3shuffle_tpu.coding import gf, gf_pallas
+    from s3shuffle_tpu.coding import gf
 
     k, m, L = 4, 2, 256
     rng = np.random.default_rng(42)
     chunks = rng.integers(0, 256, (1, k, L), dtype=np.uint8)
     coefs = gf.parity_coefficients(m, k)
-    parity = gf_pallas.encode_groups_pallas(chunks, coefs, interpret=True)
+    parity = np.asarray(gf._device_kernel(m, k)(chunks, coefs))
     recovered = gf.recover_group(
         k, coefs,
         {0: chunks[0, 0], 2: chunks[0, 2]},
@@ -312,7 +314,7 @@ def test_decode_kernel_failure_falls_back_without_frame_loss(
 
 
 def test_parity_kernel_failure_pins_host_and_counts(monkeypatch, counting):
-    from s3shuffle_tpu.coding import gf, gf_pallas
+    from s3shuffle_tpu.coding import gf
 
     def boom(*a, **kw):
         raise RuntimeError("mosaic lowering failed mid-kernel")
@@ -321,7 +323,7 @@ def test_parity_kernel_failure_pins_host_and_counts(monkeypatch, counting):
     monkeypatch.setattr(gf, "_device_broken", False)
     monkeypatch.setattr(gf, "_DEVICE_MIN_BYTES", 0)
     monkeypatch.setattr(gf, "_mesh_dispatcher", lambda: None)
-    monkeypatch.setattr(gf_pallas, "encode_groups_pallas", boom)
+    monkeypatch.setattr(gf, "_device_kernel", lambda m, k: boom)
     rng = np.random.default_rng(3)
     chunks = rng.integers(0, 256, (2, 4, 128), dtype=np.uint8)
     coefs = gf.parity_coefficients(2, 4)

@@ -23,7 +23,6 @@ WINNING = {
     "tpu_tlz_encode_mb_s": 900.0,
     "tpu_tlz_decode_mb_s": 1004.2,
     "tpu_tlz_decode_fused_mb_s": 900.0,
-    "tpu_crc32c_pallas_mb_s": 2000.0,
     "tpu_gf_encode_mb_s": 1000.0,
 }
 
@@ -32,7 +31,6 @@ LOSING = {
     "tpu_tlz_encode_mb_s": 3.6,
     "tpu_tlz_decode_mb_s": 1004.2,
     "tpu_tlz_decode_fused_mb_s": 51.2,
-    "tpu_crc32c_mb_s": 40.5,
 }
 
 
@@ -57,7 +55,7 @@ def chip_attached(monkeypatch):
 
 def test_no_probe_data_means_host():
     rates.set_rates_for_testing({})
-    for op in ("encode", "decode", "crc", "gf_encode"):
+    for op in ("encode", "decode", "gf_encode"):
         assert rates.decide(op) == (False, "no-data")
 
 
@@ -65,23 +63,14 @@ def test_measured_device_wins_over_default_host_rate():
     rates.set_rates_for_testing(WINNING)
     assert rates.decide("encode") == (True, "measured-device")
     assert rates.decide("decode") == (True, "measured-device")
-    assert rates.decide("crc") == (True, "measured-device")
     assert rates.decide("gf_encode") == (True, "measured-device")
 
 
 def test_measured_host_when_chip_loses():
     rates.set_rates_for_testing(LOSING)
     assert rates.decide("encode") == (False, "measured-host")
-    assert rates.decide("crc") == (False, "measured-host")
     # decode measured 1004 > 600 host default: the one path the chip won
     assert rates.decide("decode") == (True, "measured-device")
-
-
-def test_best_of_pallas_and_xla_represents_the_device():
-    rates.set_rates_for_testing(
-        {"tpu_crc32c_mb_s": 40.5, "tpu_crc32c_pallas_mb_s": 2000.0}
-    )
-    assert rates.decide("crc") == (True, "measured-device")
 
 
 def test_measured_host_field_overrides_default_floor():

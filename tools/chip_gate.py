@@ -4,10 +4,8 @@
 The 2026-08-04 chip probe showed the device codec plane losing to the host
 on every axis: TLZ encode 3.6 vs 435 MB/s for the host C encoder, CRC32C
 40.5 vs ~1500 MB/s native, and the fused decode collapsing 1004 MB/s to
-51 MB/s. The hand-written Pallas kernels
-(ops/crc_pallas.py, coding/gf_pallas.py) exist to close that gap; this tool
-is the scoreboard. It reads the per-metric probe cache
-(``bench_tpu_last_good.json``) and checks:
+51 MB/s. This tool is the scoreboard for closing that gap. It reads the
+per-metric probe cache (``bench_tpu_last_good.json``) and checks:
 
 - **staged floor** — each device kernel must beat the HOST implementation
   it would replace (encode >= the host C encoder, CRC >= native crc32c,
@@ -46,7 +44,7 @@ from typing import Dict, Optional, Tuple
 FLOOR_CHECKS: Tuple[Tuple[str, str, str], ...] = (
     ("tpu_tlz_encode_mb_s", "host_tlz_encode_mb_s",
      "host C TLZ encoder"),
-    ("tpu_crc32c_pallas_mb_s", "host_crc32c_mb_s", "native host crc32c"),
+    ("tpu_crc32c_mb_s", "host_crc32c_mb_s", "native host crc32c"),
     ("tpu_gf_encode_mb_s", "host_gf_encode_mb_s", "numpy GF(2^8) encoder"),
 )
 
@@ -96,67 +94,34 @@ def _default_host_rates() -> Dict[str, float]:
 
 def evaluate(table: Dict) -> Tuple[list, int, int]:
     """Gate one rate table. Returns (rows, n_failures, n_nodata) with each
-    row ``(metric, measured, target, verdict)`` already formatted.
-
-    A cache carrying ``device_classes`` (per-device-class subtables keyed by
-    device kind — the shape ``ops/rates.py class_armed`` consults on a
-    heterogeneous fleet) gets every class's OWN measurements judged against
-    the same floors, labeled ``<kind>:<metric>``: one slow device class must
-    MISS even when the fleet's fast class carries the top-level numbers."""
-    rows, failures, nodata = _evaluate_flat(table, label="")
-    classes = table.get("device_classes")
-    if isinstance(classes, dict):
-        base = {k: v for k, v in table.items() if k != "device_classes"}
-        for kind in sorted(classes):
-            sub = classes[kind]
-            if not isinstance(sub, dict):
-                continue
-            # class fields override the top level (class_armed's merge);
-            # judge only what the class itself measured — inherited numbers
-            # were already judged above
-            crows, cf, cn = _evaluate_flat(
-                {**base, **sub}, label=f"{kind}:", only=set(sub)
-            )
-            rows.extend(crows)
-            failures += cf
-            nodata += cn
-    return rows, failures, nodata
-
-
-def _evaluate_flat(
-    table: Dict, label: str = "", only: Optional[set] = None
-) -> Tuple[list, int, int]:
+    row ``(metric, measured, target, verdict)`` already formatted."""
     defaults = _default_host_rates()
     rows = []
     failures = 0
     nodata = 0
     for metric, host_metric, desc in FLOOR_CHECKS:
-        if only is not None and metric not in only:
-            continue
         floor = _num(table, host_metric) or defaults.get(
             host_metric, float("inf")
         )
         target = f">= {floor:.1f} ({desc})"
         dev = _num(table, metric)
         if dev is None:
-            rows.append((label + metric, "no data", target, "SKIP"))
+            rows.append((metric, "no data", target, "SKIP"))
             nodata += 1
             continue
         delta = (dev - floor) / floor * 100.0
         ok = dev >= floor
         rows.append((
-            label + metric, f"{dev:.1f}", target,
+            metric, f"{dev:.1f}", target,
             f"{'PASS' if ok else 'MISS'} ({delta:+.1f}%)",
         ))
         failures += 0 if ok else 1
     for fused_m, unfused_m, tol in FUSION_CHECKS:
-        if only is not None and fused_m not in only:
-            continue
         fused = _num(table, fused_m)
         unfused = _num(table, unfused_m)
         if fused is None or unfused is None:
             rows.append((
-                label + fused_m,
+                fused_m,
                 "no data" if fused is None else f"{fused:.1f}",
                 f"within {tol:.0%} of {unfused_m}",
                 "SKIP",
@@ -166,7 +131,7 @@ def _evaluate_flat(
         drift = fused / unfused - 1.0
         ok = abs(drift) <= tol
         rows.append((
-            label + fused_m, f"{fused:.1f}",
+            fused_m, f"{fused:.1f}",
             f"within {tol:.0%} of {unfused_m} ({unfused:.1f})",
             f"{'PASS' if ok else 'MISS'} ({drift * 100.0:+.1f}%)",
         ))
@@ -195,17 +160,17 @@ def _selftest() -> int:
     #    last-good value, and brand-new probe fields must survive
     cached = {
         "measured_at_utc": "2026-08-04T00:00:00Z",
-        "tpu_crc32c_pallas_mb_s": 2000.0,
+        "tpu_crc32c_mb_s": 2000.0,
         "tpu_tlz_decode_mb_s": 1004.2,
         "stale_error": "gone",
     }
     fresh = {
-        "tpu_crc32c_pallas_mb_s_error": "timing jitter",
+        "tpu_crc32c_mb_s_error": "timing jitter",
         "tpu_gf_encode_mb_s": 950.0,
         "tpu_gf_encode_cold_s": 1.2,
     }
     merged = merge_probe_metrics(cached, fresh)
-    assert merged["tpu_crc32c_pallas_mb_s"] == 2000.0, merged
+    assert merged["tpu_crc32c_mb_s"] == 2000.0, merged
     assert merged["tpu_gf_encode_mb_s"] == 950.0, merged
     assert merged["tpu_gf_encode_cold_s"] == 1.2, merged
     assert merged["tpu_tlz_decode_mb_s"] == 1004.2, merged
@@ -214,7 +179,7 @@ def _selftest() -> int:
 
     # 2) a winning table passes every check
     winning = {
-        "tpu_crc32c_pallas_mb_s": 2000.0,
+        "tpu_crc32c_mb_s": 2000.0,
         "tpu_gf_encode_mb_s": 950.0,
         "tpu_tlz_decode_mb_s": 1004.2,
         "tpu_tlz_decode_fused_mb_s": 950.0,
@@ -228,7 +193,7 @@ def _selftest() -> int:
     #    floor, fused decode 20x under its unfused formulation
     losing = {
         "tpu_tlz_encode_mb_s": 3.6,
-        "tpu_crc32c_pallas_mb_s": 40.5,
+        "tpu_crc32c_mb_s": 40.5,
         "tpu_tlz_decode_mb_s": 1004.2,
         "tpu_tlz_decode_fused_mb_s": 51.2,
     }
@@ -245,32 +210,6 @@ def _selftest() -> int:
     slow_host = dict(losing, host_tlz_encode_mb_s=3.0)
     _rows, failures, _n = evaluate(slow_host)
     assert failures == 2, failures  # encode floor now met
-
-    # 6) heterogeneous fleet: per-device-class subtables are judged against
-    #    the same floors — a slow class MISSes on its own measurements even
-    #    when the fast class's top-level numbers all pass, and class rows
-    #    carry the kind label so the verdict names the offender
-    hetero = dict(
-        winning,
-        device_classes={
-            "TPU v5e": {"tpu_tlz_encode_mb_s": 700.0},
-            "TPU v4": {
-                "tpu_tlz_encode_mb_s": 3.6,   # below host C floor
-                "tpu_tlz_decode_fused_mb_s": 51.2,   # 20x under unfused
-            },
-        },
-    )
-    rows, failures, nodata = evaluate(hetero)
-    assert failures == 2, (failures, rows)
-    table = render(rows)
-    assert "TPU v4:tpu_tlz_encode_mb_s" in table, table
-    assert "TPU v4:tpu_tlz_decode_fused_mb_s" in table, table
-    v5e_rows = [r for r in rows if r[0].startswith("TPU v5e:")]
-    assert len(v5e_rows) == 1 and "PASS" in v5e_rows[0][3], v5e_rows
-    # a class measuring nothing contributes no rows (inherited top-level
-    # numbers were already judged once)
-    rows2, f2, n2 = evaluate(dict(winning, device_classes={"TPU v5e": {}}))
-    assert f2 == 0 and len(rows2) == len(evaluate(winning)[0]), rows2
 
     print("chip_gate selftest: OK")
     return 0
