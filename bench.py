@@ -757,7 +757,7 @@ def _device_kernel_rates_impl():
         enc_tables = crc_tables(None)
         delta_rate(
             lambda d: tlz._encode_fused_math(
-                d, n_groups, lambda x: crc_fn(x, *enc_tables)
+                d, n_groups, lambda x, n: crc_fn(x, n, *enc_tables)
             )[6:11],
             "tpu_tlz_encode_fused_mb_s",
         )
@@ -836,7 +836,7 @@ def _device_kernel_rates_impl():
             )
 
         # fused decode+CRC: the read pipeline's actual launch — decode planes
-        # AND each block's right-aligned literal-plane CRC remainder in one
+        # AND each block's literal-plane CRC remainder in one
         # dispatch (the validation certificate's device half; ops/tlz.py
         # _decode_fused_math). Lands in bench_tpu_last_good.json via the
         # per-metric merge like every other kernel rate.
@@ -854,7 +854,7 @@ def _device_kernel_rates_impl():
                         (lambda dr: (dr[0][:, ::997], dr[1]))(
                             tlz._decode_fused_math(
                                 m, c, sp, o, k, carry, nl, n_groups,
-                                lambda x: crc_fn_dec(x, *dec_tables),
+                                lambda x, n: crc_fn_dec(x, n, *dec_tables),
                             )
                         ),
                     ),

@@ -17,6 +17,11 @@ batch-shape-static:
 - *front alignment*: leading zero bytes with zero state leave the state at
   zero, so blocks are staged right-aligned in the (B, L) buffer and one weight
   table serves every block length;
+- *unshift*: trailing zero bytes move the remainder by the invertible
+  one-zero-byte operator Z, so a row's first ``n`` bytes can also be
+  checksummed where they lie, front-aligned and zero-masked behind: the
+  whole-row remainder times ``Z^-(L-n)``, applied as one table step per set
+  bit of ``L - n`` (the fused TLZ kernels' literal planes);
 - *init/final fixup*: the 0xFFFFFFFF init + final XOR contribute exactly
   ``crc(0^n)``, so ``crc(block) = X ⊕ zero_crc[len(block)]`` with a host-side
   table of CRCs of zero runs.
@@ -172,8 +177,41 @@ def _mat_power(mat: tuple, n: int) -> tuple:
     return result
 
 
+def _mat_inverse(mat: tuple) -> tuple:
+    """Inverse of a 32 x 32 GF(2) matrix (columns as uint32) by Gauss-Jordan
+    elimination over its rows; raises if it is singular."""
+    rows = [sum(((col >> i) & 1) << j for j, col in enumerate(mat)) for i in range(32)]
+    inv = [1 << i for i in range(32)]
+    for c in range(32):
+        p = next((r for r in range(c, 32) if (rows[r] >> c) & 1), None)
+        if p is None:
+            raise ValueError("singular GF(2) matrix")
+        rows[c], rows[p] = rows[p], rows[c]
+        inv[c], inv[p] = inv[p], inv[c]
+        for r in range(32):
+            if r != c and (rows[r] >> c) & 1:
+                rows[r] ^= rows[c]
+                inv[r] ^= inv[c]
+    return tuple(sum(((inv[i] >> j) & 1) << i for i in range(32)) for j in range(32))
+
+
+@functools.lru_cache(maxsize=None)
+def _unshift_bits(poly: int, length: int) -> np.ndarray:
+    """``(S, 32, 32)`` int8, ``S = length.bit_length()``: entry ``[i, j, k]``
+    is bit k of ``Z^-(2^i)`` applied to bit j, Z the one-zero-byte operator.
+    Steps 0..S-1 undo any trailing zero run up to ``length`` bytes."""
+    step = _mat_inverse(_zero_op_matrix(poly))
+    out = np.zeros((length.bit_length(), 32, 32), dtype=np.int8)
+    for i in range(out.shape[0]):
+        out[i] = (np.asarray(step, dtype=np.uint32)[:, None]
+                  >> np.arange(32, dtype=np.uint32)[None, :]) & 1
+        step = _mat_mul(step, step)
+    return out
+
+
 # ---------------------------------------------------------------------------
-# Device kernels (XLA; jitted). Inputs are right-aligned (front-padded) rows.
+# Device kernels (XLA; jitted). The batch kernels take right-aligned
+# (front-padded) rows; the in-graph hook takes front-aligned ones.
 # ---------------------------------------------------------------------------
 
 
@@ -188,7 +226,7 @@ def _crc_math(data_u8, w_bits, length: int):
     """Raw (unjitted) CRC computation — shared by the standalone kernel and
     larger fused traces (see __graft_entry__)."""
     jax, jnp = _jax()
-    # data_u8: (B, L) uint8, right-aligned. w_bits: (L*8, 32) int8.
+    # data_u8: (B, L) uint8, remainder of each whole row. w_bits: (L*8, 32) int8.
     b = data_u8.shape[0]
     shifts = jnp.arange(8, dtype=jnp.uint8)
     bits = (data_u8[:, :, None] >> shifts[None, None, :]) & jnp.uint8(1)
@@ -199,8 +237,35 @@ def _crc_math(data_u8, w_bits, length: int):
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
     )  # (B, 32) — MXU int8 matmul, exact int32 accumulation
+    return _parity_word(counts)
+
+
+def _parity_word(counts):
+    """(B, 32) int32 popcounts -> (B,) uint32 of their parities, bit j from
+    column j."""
+    _jax_mod, jnp = _jax()
     parity = (counts & 1).astype(jnp.uint32)
     return jnp.sum(parity << jnp.arange(32, dtype=jnp.uint32)[None, :], axis=1, dtype=jnp.uint32)
+
+
+def _unshift_math(raw, pad, unshift_bits):
+    """Each raw remainder ``raw[b]`` times ``Z^-pad[b]``: the remainder of a
+    row with its ``pad[b]`` trailing zero bytes taken off. One step per bit
+    of ``pad`` (``unshift_bits``: :func:`_unshift_bits`), each a (B, 32) x
+    (32, 32) parity matmul kept where that bit is set; pad 0 passes ``raw``
+    through."""
+    jax, jnp = _jax()
+    bit_idx = jnp.arange(32, dtype=jnp.uint32)
+    for i in range(unshift_bits.shape[0]):
+        bits = ((raw[:, None] >> bit_idx[None, :]) & jnp.uint32(1)).astype(jnp.int8)
+        counts = jax.lax.dot_general(
+            bits,
+            unshift_bits[i],
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        )
+        raw = jnp.where(((pad >> i) & 1) == 1, _parity_word(counts), raw)
+    return raw
 
 
 @functools.lru_cache(maxsize=8)
@@ -261,20 +326,27 @@ def zero_run_crcs(poly: int, length: int) -> np.ndarray:
 
 
 def raw_crc_graph_fn(poly: int, length: int):
-    """``(fn, tables)``: a traceable ``fn(data_u8, w_bits) -> (B,) uint32``
-    raw zero-init remainder op (the MXU bit-matmul) for right-aligned
-    ``(B, length)`` rows, safe to call INSIDE a larger jit trace — the
-    hook the fused TLZ kernels use to fold the CRC pass into their own
-    launch — and ``tables(device)``, its ``(w_bits,)`` weight table resident
-    on ``device``, shipped once per (poly, device). Callers pass the table
-    as a jit ARGUMENT placed on the launch's own device: closed over, the
-    (8L, 32) table (64 MiB at 256 KiB blocks) is constant-folded into every
-    program, ~45 s of TPU compile per launch shape; placed on another chip,
-    it is copied over on every launch."""
-    def fn(data_u8, w_bits):
-        return _crc_math(data_u8, w_bits, length)
+    """``(fn, tables)``: a traceable ``fn(data_u8, n_valid, w_bits, unshift)
+    -> (B,) uint32``, the raw zero-init remainder of each front-aligned
+    ``(B, length)`` row's first ``n_valid[b]`` bytes (the bytes behind them
+    must be zero), safe to call INSIDE a larger jit trace — the hook the
+    fused TLZ kernels use to fold the CRC pass into their own launch — and
+    ``tables(device)``, its ``(w_bits, unshift)`` tables resident on
+    ``device``, shipped once per (poly, device). ``fn`` is the MXU
+    bit-matmul of each whole row, then :func:`_unshift_math` by
+    ``length - n_valid``; a right-aligned row with ``n_valid = length``
+    gives its suffix's remainder. Callers pass the tables as jit ARGUMENTS
+    placed on the launch's own device: closed over, the (8L, 32) weight
+    table (64 MiB at 256 KiB blocks) is constant-folded into every program,
+    ~45 s of TPU compile per launch shape; placed on another chip, it is
+    copied over on every launch."""
+    def fn(data_u8, n_valid, w_bits, unshift):
+        return _unshift_math(_crc_math(data_u8, w_bits, length), length - n_valid, unshift)
 
-    return fn, lambda device: (_device_weights(poly, length, device),)
+    return fn, lambda device: (
+        _device_weights(poly, length, device),
+        _device_unshift(poly, length, device),
+    )
 
 
 @functools.lru_cache(maxsize=32)
@@ -285,6 +357,13 @@ def _device_weights(poly: int, length: int, device=None):
     jax, _jnp = _jax()
     w_bits, _zero = _weights.get(poly, length)
     return jax.device_put(w_bits, device)
+
+
+@functools.lru_cache(maxsize=32)
+def _device_unshift(poly: int, length: int, device=None):
+    """:func:`_unshift_bits` as a jax array resident on ``device``."""
+    jax, _jnp = _jax()
+    return jax.device_put(_unshift_bits(poly, length), device)
 
 
 @functools.lru_cache(maxsize=8)

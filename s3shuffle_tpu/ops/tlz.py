@@ -420,27 +420,36 @@ def _encode_fused_math(blocks_u8, n_groups: int, crc_fn):
     """Encode + fused CRC in ONE trace: the planes of :func:`_encode_math`
     plus, from the same launch, raw zero-init CRC remainders of (a) each raw
     input block (the framing raw-escape branch checksums stored RAW bytes)
-    and (b) each block's literal plane right-aligned (the dominant slice of
-    a TLZ payload — the host stitches the small header/metadata CRCs around
-    it with :func:`ops.checksum.crc_combine`). Both remainder batches ride
-    one (2B, L) CRC pass, so the separate checksum launch — and its second
-    H2D staging of every compressed byte — disappears."""
+    and (b) each block's literal plane (the dominant slice of a TLZ payload
+    — the host stitches the small header/metadata CRCs around it with
+    :func:`ops.checksum.crc_combine`). Both remainder batches ride one
+    (2B, L) CRC pass, so the separate checksum launch — and its second H2D
+    staging of every compressed byte — disappears. ``crc_fn(rows, n_valid)``
+    is the hook of :func:`ops.checksum.raw_crc_graph_fn`: the literal plane
+    is checksummed where it lies, masked past its literals, and each row's
+    remainder unshifted in-graph (a raw block's pad is 0)."""
     _jax_mod, jnp = _jax()
     outs = _encode_math(blocks_u8, n_groups)
     lits, n_split, n_match = outs[5], outs[7], outs[8]
     b = blocks_u8.shape[0]
-    n_bytes = n_groups * GROUP
-    n_lits = n_groups - n_match - n_split  # (B,)
-    # right-align the literal plane per row (CRC kernels take right-aligned
-    # rows: front zero padding is free under a zero-init raw remainder)
-    shift = ((n_groups - n_lits) * GROUP).astype(jnp.int32)
-    pos = jnp.arange(n_bytes, dtype=jnp.int32)
-    src = pos[None, :] - shift[:, None]
-    lits_flat = lits.reshape(b, n_bytes)
-    gathered = jnp.take_along_axis(lits_flat, jnp.maximum(src, 0), axis=1)
-    lits_right = jnp.where(src >= 0, gathered, 0).astype(jnp.uint8)
-    raw = crc_fn(jnp.concatenate([blocks_u8, lits_right], axis=0))
+    lit_bytes = (n_groups - n_match - n_split) * GROUP  # (B,)
+    raw = crc_fn(
+        jnp.concatenate([blocks_u8, _literal_plane(lits, lit_bytes)], axis=0),
+        jnp.concatenate([jnp.full((b,), n_groups * GROUP, jnp.int32), lit_bytes]),
+    )
     return outs + (raw[:b], raw[b:])
+
+
+def _literal_plane(lits, lit_bytes):
+    """``(B, n_groups, GROUP)`` literal groups in literal order as front-
+    aligned ``(B, L)`` rows, zero from byte ``lit_bytes[b]`` on: an
+    elementwise select, so a row's CRC never reads what lies past its
+    literals."""
+    _jax_mod, jnp = _jax()
+    b = lits.shape[0]
+    flat = lits.reshape(b, -1)
+    pos = jnp.arange(flat.shape[1], dtype=jnp.int32)
+    return jnp.where(pos[None, :] < lit_bytes[:, None], flat, 0).astype(jnp.uint8)
 
 
 @functools.lru_cache(maxsize=16)
@@ -463,8 +472,8 @@ def _batch_kernel(batch_rows: int, n_groups: int, poly: Optional[int]):
 
     crc_fn, tables = raw_crc_graph_fn(poly, n_groups * GROUP)
     kernel = jax.jit(
-        lambda blocks, w_bits: _encode_fused_math(
-            blocks, n_groups, lambda d: crc_fn(d, w_bits)
+        lambda blocks, *tabs: _encode_fused_math(
+            blocks, n_groups, lambda d, n: crc_fn(d, n, *tabs)
         ),
         donate_argnums=(0,),
     )
@@ -1393,30 +1402,22 @@ def _decode_fused_math(
 ):
     """Decode + fused CRC in ONE trace: the decoded rows of
     :func:`_decode_counted_math` plus, from the same launch, the raw zero-init CRC
-    remainder of each row's literal plane right-aligned — the dominant slice
-    of every stored TLZ payload. The host stitches the small header/metadata
-    prefix CRCs around it with :func:`ops.checksum.crc_combine`, so the read
-    plane certifies each frame's STORED bytes without a separate host hashing
+    remainder of each row's literal plane — the dominant slice of every
+    stored TLZ payload. The host stitches the small header/metadata prefix
+    CRCs around it with :func:`ops.checksum.crc_combine`, so the read plane
+    certifies each frame's STORED bytes without a separate host hashing
     pass over the payload bulk (the read-side mirror of
-    :func:`_encode_fused_math`). ``n_lits``: (B,) int32 literal-group counts
-    (the staged literal plane is front-aligned in literal order). Returns
-    ``(decoded, crcs, rounds)``, ``rounds`` as :func:`_decode_counted_math`."""
-    _jax_mod, jnp = _jax()
+    :func:`_encode_fused_math`, with the same masked, unshifted CRC).
+    ``n_lits``: (B,) int32 literal-group counts (the staged literal plane is
+    front-aligned in literal order; what lies past a row's literals is never
+    read). Returns ``(decoded, crcs, rounds)``, ``rounds`` as
+    :func:`_decode_counted_math`."""
     decoded, rounds = _decode_counted_math(
         is_match, is_cont, is_split, offs_padded, ks_padded, lits_padded,
         n_groups,
     )
-    b = is_match.shape[0]
-    n_bytes = n_groups * GROUP
-    # right-align the literal plane per row (CRC kernels take right-aligned
-    # rows: front zero padding is free under a zero-init raw remainder)
-    shift = ((n_groups - n_lits) * GROUP).astype(jnp.int32)
-    pos = jnp.arange(n_bytes, dtype=jnp.int32)
-    src = pos[None, :] - shift[:, None]
-    lits_flat = lits_padded.reshape(b, n_bytes)
-    gathered = jnp.take_along_axis(lits_flat, jnp.maximum(src, 0), axis=1)
-    lits_right = jnp.where(src >= 0, gathered, 0).astype(jnp.uint8)
-    return decoded, crc_fn(lits_right), rounds
+    lit_bytes = n_lits * GROUP
+    return decoded, crc_fn(_literal_plane(lits_padded, lit_bytes), lit_bytes), rounds
 
 
 @functools.lru_cache(maxsize=16)
@@ -1440,8 +1441,9 @@ def _decode_batch_kernel(batch_rows: int, n_groups: int, poly: Optional[int]):
 
     crc_fn, tables = raw_crc_graph_fn(poly, n_groups * GROUP)
     kernel = jax.jit(
-        lambda m, c, s, o, k, lits, n_lits, w_bits: _decode_fused_math(
-            m, c, s, o, k, lits, n_lits, n_groups, lambda d: crc_fn(d, w_bits)
+        lambda m, c, s, o, k, lits, n_lits, *tabs: _decode_fused_math(
+            m, c, s, o, k, lits, n_lits, n_groups,
+            lambda d, n: crc_fn(d, n, *tabs),
         ),
         donate_argnums=(0, 1, 2, 3, 4, 5),
     )

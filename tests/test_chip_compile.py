@@ -27,6 +27,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BLOCK = 256 * 1024  # TpuCodec default block_size
 ROWS = 64  # TpuCodec default batch_blocks
 N_GROUPS = BLOCK // tlz.GROUP
+UNSHIFT = (BLOCK.bit_length(), 32, 32)  # the fused CRC's unshift table
 
 
 @pytest.fixture(scope="module")
@@ -99,7 +100,9 @@ def test_crc_fold_pallas_compiles_at_fused_encode_width(one_chip):
     compiled = _compile(
         fn,
         _arg(one_chip, (b, BLOCK), jnp.uint8),
+        _arg(one_chip, (b,), jnp.int32),
         _arg(one_chip, (BLOCK * 8, 32), jnp.int8),
+        _arg(one_chip, UNSHIFT, jnp.int8),
     )
     text = compiled.as_text()
     # the int8 dot_general lowers to an MXU convolution with int32 output
@@ -139,18 +142,21 @@ def test_fused_decode_compiles(one_chip, crc):
 
     from s3shuffle_tpu.ops import checksum
 
-    # the CRC table rides as an argument: as a closure constant the
+    # the CRC tables ride as arguments: as a closure constant the
     # bit-matmul's 64 MiB weight table takes ~50 s of constant folding
-    def fused(m, c, s, o, k, lits, n_lits, w_bits):
+    crc_fn, _tables = checksum.raw_crc_graph_fn(POLY_CRC32C, BLOCK)
+
+    def fused(m, c, s, o, k, lits, n_lits, w_bits, unshift):
         return tlz._decode_fused_math(
             m, c, s, o, k, lits, n_lits, N_GROUPS,
-            lambda data: checksum._crc_math(data, w_bits, BLOCK),
+            lambda data, n: crc_fn(data, n, w_bits, unshift),
         )
 
     compiled = _compile(
         fused, *_decode_args(one_chip, ROWS),
         _arg(one_chip, (ROWS,), jnp.int32),
         _arg(one_chip, (BLOCK * 8, 32), jnp.int8),
+        _arg(one_chip, UNSHIFT, jnp.int8),
     )
     assert compiled.memory_analysis() is not None
 
@@ -175,6 +181,7 @@ def test_fused_encode_program_holds_the_bit_matmul(one_chip, monkeypatch):
     text = program.lower(
         _arg(one_chip, (ROWS, BLOCK), jnp.uint8),
         _arg(one_chip, (BLOCK * 8, 32), jnp.int8),
+        _arg(one_chip, UNSHIFT, jnp.int8),
     ).as_text()
     assert re.search(
         r"stablehlo\.dot_general .*%arg1.*: \(tensor<128x2097152xi8>, "

@@ -185,7 +185,7 @@ def test_pallas_crc_matches_host_remainder(staged):
     length = rows.shape[1]
     suffixes = [bytes(row[length - n:]) for row, n in zip(rows, lens)]
     fn, tables = raw_crc_graph_fn(POLY_CRC32C, length)
-    raw = jax.jit(fn)(rows, *tables(None))
+    raw = jax.jit(fn)(rows, np.full(len(rows), length, np.int32), *tables(None))
     assert [int(c) for c in raw] == [
         _crc_raw_bytes(s, POLY_CRC32C, 0) & 0xFFFFFFFF for s in suffixes
     ]
