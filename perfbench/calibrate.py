@@ -8,7 +8,10 @@ For each seed of ``--seeds`` one short window of the cell as committed; for
 each of ``--control-seeds`` one window of the control: the program with its
 stored checksum switched from CRC32C to ADLER32 (``checksum_algorithm``, a
 path of the program's own), which breaks a guarantee the configuration
-states. Both print every number compared, one JSON line per run. The kernels
+states. The store check reads ADLER32 too, but holds a run to the
+algorithm the configuration states, so the control's store, which has no
+``checksum.CRC32C`` object, counts every partition as a mismatch. Both
+print every number compared, one JSON line per run. The kernels
 compile once: only the first run of each kind has a warm-up job. The
 benchmark's own runs never run this. ``--trace-excerpt`` also traces the
 first seed's window and writes a slice of the trace, the fixture of

@@ -258,9 +258,17 @@ def run_cell(bench, cell, config, traffic, seed: int, seconds: float, trace: boo
             crc_checked += c
             crc_wrong += bad
         checks["checksum_mismatches"] = crc_wrong
+        stated = _stated_geometry(config["shuffle"])
+        par_checked = par_wrong = 0
+        for sid in window_sids:
+            c, bad = store.parity_mismatches(root, sid, stated)
+            par_checked += c
+            par_wrong += bad
+        checks["parity_groups_wrong"] = par_wrong
         ex.unregister(window_sids)
         observe("checked", {"jobs": [i for i, _o in retained],
-                            "partitions_crc_checked": crc_checked})
+                            "partitions_crc_checked": crc_checked,
+                            "parity_groups_checked": par_checked})
     finally:
         ex.stop()
         shutil.rmtree(root, ignore_errors=True)
@@ -308,6 +316,15 @@ def run_cell(bench, cell, config, traffic, seed: int, seconds: float, trace: boo
                                "idle_gaps": trace_reduce.top(reduced["idle_gaps"])}
     result["checks"] = {k: {"value": v, "limit": 0} for k, v in checks.items()}
     return result
+
+
+def _stated_geometry(shuffle: dict):
+    """The parity geometry ``(m, k, chunk_bytes)`` a configuration states,
+    or None where it states no parity."""
+    if not shuffle.get("parity_segments"):
+        return None
+    return (shuffle["parity_segments"], shuffle["parity_stripe_k"],
+            shuffle["parity_chunk_bytes"])
 
 
 def _kernels() -> dict:
